@@ -184,6 +184,8 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="JSON artifact path (default BENCH_<smoke>.json)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.smoke == "detect":
         smoke_detect(args.n_slices, args.out or "BENCH_detect.json")
         return

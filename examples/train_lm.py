@@ -14,6 +14,7 @@ import argparse
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.core import ProfileSession, render_text
 from repro.models.common import ModelConfig
 from repro.optim import adamw
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=256)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = build_cfg(args.dmodel)
     n_params = cfg.param_count()

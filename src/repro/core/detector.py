@@ -226,10 +226,13 @@ def _attach_samples(crit: SliceTable, samples: SampleBuffer | None):
     return np.concatenate(rows), np.concatenate(tags)
 
 
-def _pallas_hist_native() -> bool:
-    """True when the Pallas ``tag_hist`` kernel compiles natively — in
-    interpret mode (off-TPU) ``np.bincount`` is far faster, so the fused
-    backend only routes the histogram on real TPU hardware."""
+def pallas_hist_for(backend: str) -> bool:
+    """True when ``backend`` is the fused device pipeline and the Pallas
+    ``tag_hist`` kernel compiles natively — in interpret mode (off-TPU)
+    ``np.bincount`` is far faster, so the histogram is routed to the
+    kernel only on real TPU hardware."""
+    if "fused" not in backends_lib.get_backend(backend).capabilities:
+        return False
     from repro.kernels import ops
     return not ops.default_interpret()
 
@@ -409,6 +412,8 @@ def detect(
         idle_time=snap["idle_time"],
         total_time=snap["total_time"],
         top_n=top_n,
+        use_pallas_hist=pallas_hist_for(
+            getattr(tracer, "fold_backend", "numpy")),
     )
     from repro.core.whatif import ReplaySpec
     rep.replay = ReplaySpec(
@@ -475,7 +480,6 @@ def detect_offline(
         crit = res.critical_table(n_min)
         per_worker, idle, total = res.per_worker, res.idle_time, res.total_time
         num_slices = res.num_slices
-    caps = backends_lib.get_backend(backend).capabilities
     rep = build_report(
         crit, samples, stacks, n_min,
         per_worker=per_worker,
@@ -486,7 +490,7 @@ def detect_offline(
         idle_time=idle,
         total_time=total,
         top_n=top_n,
-        use_pallas_hist="fused" in caps and _pallas_hist_native(),
+        use_pallas_hist=pallas_hist_for(backend),
     )
     from repro.core.whatif import ReplaySpec
     rep.replay = ReplaySpec(

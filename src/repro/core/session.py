@@ -511,10 +511,6 @@ class ProfileSession:
     def stacks(self) -> StackRegistry:
         return self.source.stacks
 
-    def _use_pallas_hist(self) -> bool:
-        caps = backends_lib.get_backend(self.fold_backend).capabilities
-        return "fused" in caps and detector_lib._pallas_hist_native()
-
     def snapshot(self, top_n: int | None = None):
         """Incremental :class:`BottleneckReport` from the state folded so
         far — callable at any time, concurrently with capture (one sync
@@ -543,7 +539,7 @@ class ProfileSession:
             idle_time=st["idle_time"],
             total_time=st["total_time"],
             top_n=top_n,
-            use_pallas_hist=self._use_pallas_hist(),
+            use_pallas_hist=detector_lib.pallas_hist_for(self.fold_backend),
             worker_hosts=self.source.worker_hosts(),
         )
         if hasattr(self.source, "full_log"):

@@ -13,9 +13,10 @@ i.e. two coupled prefix scans over the event stream.  TPU adaptation: the
 stream is tiled into (1, B) VMEM blocks (B a multiple of 128 lanes); within a
 block the scan is a Hillis–Steele shift-add ladder (log2 B vector steps on
 the VPU); the inter-block carry (running count, running gcm, idle time) lives
-in a small VMEM scratch accumulator that persists across the sequential TPU
-grid.  HBM traffic is exactly 3 input + 2 output streams — the kernel is
-memory-bound by design, matching its roofline on the VPU.
+in a small VMEM accumulator block that persists across the sequential TPU
+grid, written whole (Mosaic stores no scalars into VMEM).  HBM traffic is
+exactly 3 input + 2 output streams — the kernel is memory-bound by design,
+matching its roofline on the VPU.
 
 Both kernels are **carry-resumable**: the scan state enters as a small
 ``carry0`` input and the final state comes back in the scalars output, so a
@@ -49,22 +50,32 @@ def _ladder_cumsum(x):
     return x
 
 
+def _lanes(*vals):
+    """Pack (1, 1) values into lanes 0..k-1 of one (1, LANES) row.
+
+    The TPU has no scalar store into VMEM, so the carry and the final
+    scalars are written as whole blocks built with a lane ``iota``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    out = jnp.zeros((1, LANES), jnp.float32)
+    for k, v in enumerate(vals):
+        out = jnp.where(lane == k, v, out)
+    return out
+
+
 def _fold_kernel(dt_ref, delta_ref, carry0_ref, n_ref, gcm_ref, carry_ref,
                  scalars_ref):
     """Grid is 1-D over event blocks; TPU executes it sequentially, so the
-    carry scratch implements the cross-block prefix.  ``carry0`` seeds the
+    carry block implements the cross-block prefix.  ``carry0`` seeds the
     scan (count, gcm, idle) so a chunked caller can resume a prior fold."""
     blk = pl.program_id(0)
 
     @pl.when(blk == 0)
     def _init():
-        carry_ref[0, 0] = carry0_ref[0, 0]   # running count (f32; exact to 2^24)
-        carry_ref[0, 1] = carry0_ref[0, 1]   # running gcm
-        carry_ref[0, 2] = carry0_ref[0, 2]   # running idle time
+        carry_ref[...] = carry0_ref[...]
 
-    count_in = carry_ref[0, 0]
-    gcm_in = carry_ref[0, 1]
-    idle_in = carry_ref[0, 2]
+    count_in = carry_ref[:, 0:1]   # running count (f32; exact to 2^24)
+    gcm_in = carry_ref[:, 1:2]     # running gcm
+    idle_in = carry_ref[:, 2:3]    # running idle time
 
     delta = delta_ref[...].astype(jnp.float32)
     dt = dt_ref[...]
@@ -74,20 +85,20 @@ def _fold_kernel(dt_ref, delta_ref, carry0_ref, n_ref, gcm_ref, carry_ref,
     contrib = jnp.where(pos, dt / jnp.maximum(n, 1.0), 0.0)
     incl = _ladder_cumsum(contrib)
     gcm = gcm_in + incl - contrib                    # exclusive prefix
-    idle_blk = jnp.sum(jnp.where((~pos) & (dt > 0), dt, 0.0))
+    idle_blk = jnp.sum(jnp.where((~pos) & (dt > 0), dt, 0.0), axis=1,
+                       keepdims=True)
 
     n_ref[...] = n.astype(jnp.int32)
     gcm_ref[...] = gcm
 
-    carry_ref[0, 0] = n[0, -1]
-    carry_ref[0, 1] = gcm_in + incl[0, -1]
-    carry_ref[0, 2] = idle_in + idle_blk
+    count = n[:, -1:]
+    total = gcm_in + incl[:, -1:]
+    idle = idle_in + idle_blk
+    carry_ref[...] = _lanes(count, total, idle)
 
     @pl.when(blk == pl.num_programs(0) - 1)
     def _finalize():
-        scalars_ref[0, 0] = gcm_in + incl[0, -1]     # total_cm
-        scalars_ref[0, 1] = idle_in + idle_blk       # idle
-        scalars_ref[0, 2] = n[0, -1]                 # final count
+        scalars_ref[...] = _lanes(total, idle, count)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -152,24 +163,23 @@ def _cumsum_kernel(contrib_ref, idle_ref, carry0_ref, g_ref, carry_ref,
 
     @pl.when(blk == 0)
     def _init():
-        carry_ref[0, 0] = carry0_ref[0, 0]   # running gcm
-        carry_ref[0, 1] = carry0_ref[0, 1]   # running idle
+        carry_ref[...] = carry0_ref[...]
 
-    g_in = carry_ref[0, 0]
-    idle_in = carry_ref[0, 1]
+    g_in = carry_ref[:, 0:1]       # running gcm
+    idle_in = carry_ref[:, 1:2]    # running idle
 
     contrib = contrib_ref[...]
     incl = _ladder_cumsum(contrib)
     g_ref[...] = g_in + incl                  # inclusive: gcm *at* event i
-    idle_blk = jnp.sum(idle_ref[...])
+    idle_blk = jnp.sum(idle_ref[...], axis=1, keepdims=True)
 
-    carry_ref[0, 0] = g_in + incl[0, -1]
-    carry_ref[0, 1] = idle_in + idle_blk
+    g_end = g_in + incl[:, -1:]
+    idle = idle_in + idle_blk
+    carry_ref[...] = _lanes(g_end, idle)
 
     @pl.when(blk == pl.num_programs(0) - 1)
     def _finalize():
-        scalars_ref[0, 0] = g_in + incl[0, -1]
-        scalars_ref[0, 1] = idle_in + idle_blk
+        scalars_ref[...] = _lanes(g_end, idle)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))

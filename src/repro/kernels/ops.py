@@ -1,7 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; TPU v5e
-is the compile target) and False on real TPU backends.
+``interpret`` defaults to False on a TPU backend, where the kernels compile
+natively through Mosaic (TPU v5e is the target), and to True on every
+other backend, where Pallas interprets them (the CPU test suite).
 """
 from __future__ import annotations
 

@@ -11,18 +11,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types across JAX versions.
-
-    ``jax.sharding.AxisType`` (and the ``axis_types`` kwarg taking it) only
-    exists in newer JAX releases — on older ones the attribute access raises
-    through the deprecation machinery.  Auto is the default everywhere, so
-    the kwarg is passed only when the enum is present.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -68,10 +67,10 @@ def gpipe(stage_fn, mesh: Mesh, n_stages: int, n_micro: int,
             return outs
 
         pspec = jax.tree.map(lambda _: P(stage_axis), stacked_params)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(pspec, P()), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(stacked_params, x)
 
     return pipelined

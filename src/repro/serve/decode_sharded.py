@@ -21,7 +21,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import ModelConfig
@@ -56,10 +55,10 @@ def flash_decode_local(q, k_local, v_local, valid_local, axis_name: str):
 def make_flash_decode(mesh, cfg: ModelConfig, axis_name: str = "model"):
     """Returns f(q, k, v, valid) with k/v sequence-sharded over axis_name."""
     fn = functools.partial(flash_decode_local, axis_name=axis_name)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(), P(None, axis_name, None, None),
                   P(None, axis_name, None, None), P(None, axis_name)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )

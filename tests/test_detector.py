@@ -7,11 +7,11 @@ from repro.core import (SampleBuffer, Tracer, detect, detect_offline,
 from tests.test_tracer import FakeClock
 
 
-def _bottleneck_trace(n_min=1.9):
+def _bottleneck_trace(n_min=1.9, fold_backend="numpy"):
     """3 workers: w0/w1 parallel bursts, w2 long serial sections under two
     different call paths."""
     clk = FakeClock()
-    tr = Tracer(n_min=n_min, clock=clk)
+    tr = Tracer(n_min=n_min, clock=clk, fold_backend=fold_backend)
     w = [tr.register_worker(f"w{i}") for i in range(3)]
     for rep in range(8):
         tr.begin(w[0], "par")
@@ -85,6 +85,30 @@ def test_sample_attachment_window():
             counts[t] = counts.get(t, 0) + c
     assert counts.get(7) == 1
     assert 9 not in counts
+
+
+@pytest.mark.parametrize("backend,routed", [("numpy", False),
+                                             ("pallas", True)])
+def test_live_histogram_route_follows_fold_backend(monkeypatch, backend,
+                                                   routed):
+    """Where the kernels compile natively, a live tracer on the fused
+    backend builds its tag tables with the Pallas ``tag_hist`` kernel."""
+    from repro.kernels import ops
+    tr, clk, w = _bottleneck_trace(fold_backend=backend)
+    crit = tr.critical[2]                    # folds every pending event
+    buf = SampleBuffer()
+    buf.append((crit.start_ns + crit.end_ns) // 2, crit.worker, 7)
+    calls = []
+    hist = ops.tag_histogram
+
+    def counted(*a, **k):
+        calls.append(k["num_bins"])
+        return hist(*a, **{**k, "interpret": True})
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    monkeypatch.setattr(ops, "tag_histogram", counted)
+    rep = detect(tr, buf, top_n=5)
+    assert bool(calls) == routed
+    assert rep.paths[0].tag_counts == {7: 1}
 
 
 def test_offline_pipeline_with_simulated_sampler():
