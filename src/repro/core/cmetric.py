@@ -55,6 +55,7 @@ from repro.core import backends as backends_lib
 from repro.core.backends import register_backend
 from repro.core.events import ACTIVATE, DEACTIVATE, EventLog
 from repro.core.slices import CriticalTable, SliceTable
+from repro.obs import spans
 
 
 @dataclasses.dataclass
@@ -466,6 +467,8 @@ def _prefix_vector(carry: FoldCarry, contrib, idle_contrib):
                                  jnp.float32(carry.idle),
                                  jnp.asarray(contrib, jnp.float32),
                                  jnp.asarray(idle_contrib, jnp.float32))
+    with spans.span("profiler/fold_wait"):
+        jax.block_until_ready((g, idle))
     return np.asarray(g, np.float64), float(idle)
 
 

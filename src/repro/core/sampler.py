@@ -25,6 +25,7 @@ import threading
 import numpy as np
 
 from repro.core.tracer import Tracer
+from repro.obs import spans
 
 
 class SampleBuffer:
@@ -93,12 +94,13 @@ class SamplingProbe:
         self.ticks += 1
         if self.tracer.thread_count >= self._resolved_n_min():
             return 0
-        t = self.tracer.clock() if t is None else t
-        taken = 0
-        for wid, tag in self.tracer.active_tags():
-            self.buffer.append(t, wid, tag)
-            taken += 1
-        self.hits += taken
+        with spans.span("profiler/sample"):
+            t = self.tracer.clock() if t is None else t
+            taken = 0
+            for wid, tag in self.tracer.active_tags():
+                self.buffer.append(t, wid, tag)
+                taken += 1
+            self.hits += taken
         return taken
 
     def _run(self) -> None:

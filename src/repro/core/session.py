@@ -76,6 +76,7 @@ from repro.core.sampler import SampleBuffer, SamplingProbe, simulate_samples
 from repro.core.slices import CriticalBuffer
 from repro.core.spill import SpillStore
 from repro.core.tracer import StackRegistry, TagRegistry, Tracer
+from repro.obs import spans
 
 
 # ---------------------------------------------------------------------------
@@ -417,8 +418,9 @@ class ProfileSession:
             self._carry.ensure_workers(part.num_workers)
             part, _, keep = sanitize_chunk(part, self._carry.open)
             self._sanitize_dropped += int(keep.size - keep.sum())
-            self._carry, tbl = backends_lib.fold_chunk(
-                self._carry, part, backend=self.fold_backend)
+            with spans.span("profiler/fold"):
+                self._carry, tbl = backends_lib.fold_chunk(
+                    self._carry, part, backend=self.fold_backend)
             self._crit.extend_table(tbl, tbl.threads_av < self._resolved_n_min())
             self._folded += len(part)
 

@@ -61,6 +61,7 @@ from repro.core.events import (ACTIVATE, DEACTIVATE, NO_STACK, NO_TAG,
                                EventLog, EventRing, EventStore,
                                ShardedEventRing, tolerance_keep)
 from repro.core.slices import CriticalBuffer, CriticalSlice  # noqa: F401 (re-export)
+from repro.obs import spans
 
 
 @dataclasses.dataclass
@@ -322,7 +323,7 @@ class Tracer:
         present at entry is consumed in budget-sized flushes (bounded even
         under a live producer — rows appended *during* the sync stay
         pending, exactly like the unbudgeted single-pass drain)."""
-        with self._fold_lock:
+        with spans.span("profiler/drain"), self._fold_lock:
             if self.max_rows_per_sync is None:
                 self._flush_locked()
                 return
@@ -338,12 +339,14 @@ class Tracer:
         at most ``max_rows_per_sync`` rows per shard, so a mid-capture
         ``snapshot()`` waiting on the fold lock is never stuck behind an
         unbounded decode.  Returns the rows still pending after it."""
-        with self._fold_lock:
-            self._flush_locked(self.max_rows_per_sync)
-        return self.ring.pending()
+        with spans.span("profiler/drain"):
+            with self._fold_lock:
+                self._flush_locked(self.max_rows_per_sync)
+            return self.ring.pending()
 
     def _flush_locked(self, limit: int | None = None) -> int:  # guarded-by: self._fold_lock
-        chunk = self.ring.drain(limit)
+        with spans.span("profiler/merge"):
+            chunk = self.ring.drain(limit)
         # total_count *after* the drain: a worker that registered while we
         # drained may already have events in the chunk, and every map below
         # must cover its id
@@ -377,18 +380,20 @@ class Tracer:
             return drained
         stacks_col = np.full(times.shape[0], NO_STACK, np.int32)
         clog = EventLog(times, workers, deltas, tags, stacks_col, w_count)
-        self._carry, table = backends_lib.fold_chunk(
-            carry, clog, backend=self.fold_backend)
+        with spans.span("profiler/fold"):
+            self._carry, table = backends_lib.fold_chunk(
+                carry, clog, backend=self.fold_backend)
         # §4.2: intern call paths for critical timeslices only
         crit_mask = table.threads_av < self._resolved_n_min()
         if crit_mask.any():
-            deact_pos = np.flatnonzero(deltas == DEACTIVATE)
-            aux_out = aux[deact_pos]
-            intern_cons = self.stacks.intern_cons
-            for r in np.flatnonzero(crit_mask):
-                sid = intern_cons(aux_out[r])
-                table.stack_id[r] = sid
-                stacks_col[deact_pos[r]] = sid
+            with spans.span("profiler/intern"):
+                deact_pos = np.flatnonzero(deltas == DEACTIVATE)
+                aux_out = aux[deact_pos]
+                intern_cons = self.stacks.intern_cons
+                for r in np.flatnonzero(crit_mask):
+                    sid = intern_cons(aux_out[r])
+                    table.stack_id[r] = sid
+                    stacks_col[deact_pos[r]] = sid
             self._critical.extend_table(table, crit_mask)
         self._store.append_columns(times, workers, deltas, tags, stacks_col)
         for sink in self.sinks:

@@ -15,6 +15,7 @@ import threading
 import numpy as np
 
 from repro.core.session import ProfileSession
+from repro.obs import spans
 
 
 class SyntheticLM:
@@ -67,7 +68,8 @@ class PrefetchLoader:
         while not self._stop.is_set():
             if self.gapp is not None:
                 self.gapp.begin(self._wid, "data/generate")
-            batch = self.source.next_batch()
+            with spans.span("data/generate"):
+                batch = self.source.next_batch()
             if self.delay_s:
                 time.sleep(self.delay_s)
             if self.gapp is not None:

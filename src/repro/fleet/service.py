@@ -23,7 +23,8 @@ browser at a running fleet" product shape over everything the durable
   ``watch`` exporter delivers (one builder: :mod:`repro.obs.payload`);
 * ``GET /metrics``     — Prometheus text exposition of the profiler's
   self-telemetry (fold rate, snapshot latency, queue depths, shed/lost/
-  duplicate chunks, journal bytes).
+  duplicate chunks, journal bytes, the host spans of
+  :mod:`repro.obs.spans` as ``gapp_span_*``).
 
 Like the ingest side, the server is ONE selector thread — the handler
 must never block on disk or the session's locks longer than a snapshot
@@ -66,7 +67,7 @@ from repro.fleet.aggregate import (FleetSource, fleet_dir_time_span,
                                    journal_on_disk, load_json)
 from repro.obs import http
 from repro.obs import payload as payload_lib
-from repro.obs import prom
+from repro.obs import prom, spans
 from repro.obs.dashboard import DASHBOARD_HTML
 
 #: /api/top responses and /api/stream frames share the payload schema
@@ -729,6 +730,7 @@ class ProfilerService:
             samples.append(("gapp_service_requests", {"route": route},
                             float(count)))
         samples.extend(prom.flatten_stats("gapp_service", svc))
+        samples.extend(prom.flatten_stats("gapp_span", spans.stats()))
         st = self.session.stats()
         source = st.pop("source", None)
         sinks = st.pop("sinks", None)

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.kernels import cmetric_fold as _fold
 from repro.kernels import tag_hist as _hist
+from repro.obs import spans
 
 
 def default_interpret() -> bool:
@@ -42,7 +43,9 @@ def fold_chunk_prefix(gcm0: float, idle0: float, contrib, idle_contrib, *,
     the per-event contributions on the Pallas scan kernel.
 
     Returns ``(g float64[E], idle_end float)`` where ``g[i]`` is the
-    global_cm value at event ``i``.
+    global_cm value at event ``i``.  The wait for the device's result
+    (which queues behind whatever the device runs already) is the span
+    ``profiler/fold_wait``.
     """
     interpret = default_interpret() if interpret is None else interpret
     g, _, idle_end = _fold.carry_cumsum(
@@ -50,6 +53,8 @@ def fold_chunk_prefix(gcm0: float, idle0: float, contrib, idle_contrib, *,
         jnp.asarray(idle_contrib, jnp.float32),
         jnp.asarray([gcm0, idle0], jnp.float32),
         block=block, interpret=interpret)
+    with spans.span("profiler/fold_wait"):
+        jax.block_until_ready((g, idle_end))
     return np.asarray(g, np.float64), float(idle_end)
 
 

@@ -11,15 +11,20 @@ Small, dependency-free building blocks the serving layer
 * :mod:`repro.obs.payload` — the shared top-N/host-lanes payload builder
   behind ``session.watch(..., payload=True)`` and ``GET /api/stream``;
 * :mod:`repro.obs.dashboard` — the inline no-dependency HTML dashboard
-  served at ``GET /``.
+  served at ``GET /``;
+* :mod:`repro.obs.spans` — named host spans on the program's own threads,
+  in JAX traces and as ``count`` / ``seconds_sum`` / ``seconds_max``.
+
+``repro.core`` imports :mod:`repro.obs.spans`, so this package's own
+import pulls in nothing from ``repro.core`` (import ``build_watch_payload``
+from :mod:`repro.obs.payload`).
 """
 from repro.obs.http import (HttpError, Request, chunk, parse_request,
                             response, stream_head)
-from repro.obs.payload import build_watch_payload
 from repro.obs.prom import flatten_stats, render_metrics
 
 __all__ = [
-    "HttpError", "Request", "build_watch_payload", "chunk",
+    "HttpError", "Request", "chunk",
     "flatten_stats", "parse_request", "render_metrics", "response",
     "stream_head",
 ]

@@ -19,6 +19,7 @@ from repro.core.session import ProfileSession
 from repro.data.pipeline import PrefetchLoader, SyntheticLM
 from repro.models import init_lm
 from repro.models.common import ModelConfig
+from repro.obs import spans
 from repro.optim import adamw
 from repro.train.step import make_train_step
 
@@ -109,23 +110,29 @@ class Trainer:
                 # semantics — a blocked thread leaves TASK_RUNNING), so a
                 # slow loader runs alone and its data/generate slices are
                 # the ones that turn critical
-                batch = self.loader.get()
+                with spans.span("train/loader_wait"):
+                    batch = self.loader.get()
                 if g:
                     g.begin(self.w_train, "train/step")
-                batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
-                params, opt_state, metrics, err = self.step_fn(
-                    params, opt_state, batch, err)
-                jax.block_until_ready(metrics["loss"])
+                with spans.span("train/h2d"):
+                    batch = {k: jax.numpy.asarray(v)
+                             for k, v in batch.items()}
+                with spans.span("train/step"):
+                    params, opt_state, metrics, err = self.step_fn(
+                        params, opt_state, batch, err)
+                    jax.block_until_ready(metrics["loss"])
                 if g:
                     g.end(self.w_train)
-                self.history.append(
-                    {k: float(np.asarray(v)) for k, v in metrics.items()
-                     if v is not None and np.ndim(v) == 0})
-                if step % self.tcfg.log_every == 0:
-                    print(f"step {step:5d} loss {self.history[-1]['loss']:.4f}"
-                          f" gnorm {self.history[-1].get('grad_norm', 0):.3f}",
-                          flush=True)
-                self._maybe_ckpt(step + 1, params, opt_state)
+                with spans.span("train/host"):
+                    self.history.append(
+                        {k: float(np.asarray(v)) for k, v in metrics.items()
+                         if v is not None and np.ndim(v) == 0})
+                    if step % self.tcfg.log_every == 0:
+                        print(f"step {step:5d} loss "
+                              f"{self.history[-1]['loss']:.4f} gnorm "
+                              f"{self.history[-1].get('grad_norm', 0):.3f}",
+                              flush=True)
+                    self._maybe_ckpt(step + 1, params, opt_state)
             self._maybe_ckpt(self.tcfg.steps, params, opt_state, final=True)
             if self._ckpt_thread is not None:
                 self._ckpt_thread.join()

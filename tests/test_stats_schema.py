@@ -7,11 +7,16 @@ is a breaking change this test catches; NEW keys are additive and only
 require updating the pinned set (and the docstring, which this test also
 enforces for the session).
 """
+import re
 import time
+import urllib.request
+from pathlib import Path
 
+import repro
 from repro.core import ProfileSession
 from repro.fleet import (IngestServer, ProfilerService, RemoteSink,
                          attach_remote)
+from repro.obs import spans
 from repro.obs.prom import flatten_stats
 from tests.test_tracer import FakeClock
 
@@ -49,6 +54,15 @@ SERVICE_KEYS = {
     "window_fold_seconds_sum", "whatif_folds", "whatif_fold_seconds_sum",
     "max_window_s", "retention_pruned_blocks", "retention_errors",
 }
+
+# host spans (repro.obs.spans): every name the program enters, and the keys
+# of each name's aggregate
+SPAN_NAMES = {
+    "train/loader_wait", "train/h2d", "train/step", "train/host",
+    "data/generate", "profiler/drain", "profiler/merge", "profiler/fold",
+    "profiler/fold_wait", "profiler/intern", "profiler/sample",
+}
+SPAN_KEYS = {"count", "seconds_sum", "seconds_max"}
 
 
 def test_session_live_stats_schema():
@@ -133,3 +147,39 @@ def test_metric_names_derived_from_schema_are_stable():
         "gapp_session_samples_dropped", "gapp_session_watch_errors",
     }   # "mode" is a string -> identity, not telemetry
     s.result()
+
+
+def test_program_span_names_are_pinned():
+    """The benchmark's per-layer metrics and the ``gapp_span_*`` gauges
+    read spans by name: a renamed span fails here first."""
+    names = set()
+    for path in Path(repro.__file__).parent.rglob("*.py"):
+        names.update(re.findall(r'spans\.span\("([^"]+)"\)',
+                                path.read_text()))
+    assert names == SPAN_NAMES
+
+
+def test_span_stats_are_exported_at_metrics(monkeypatch):
+    monkeypatch.setattr(spans, "_LOG", spans.SpanLog())
+    for name in SPAN_NAMES:
+        with spans.span(name):
+            pass
+    st = spans.stats()
+    assert set(st) == SPAN_NAMES | {"records_dropped"}
+    assert all(set(st[n]) == SPAN_KEYS for n in SPAN_NAMES)
+    want = {f"gapp_span_{n.replace('/', '_')}_{k}"
+            for n in SPAN_NAMES for k in SPAN_KEYS}
+    assert {n for n, _, _ in flatten_stats("gapp_span", st)} \
+        == want | {"gapp_span_records_dropped"}
+    s = ProfileSession(n_min=1.0, clock=FakeClock())
+    svc = s.serve()
+    try:
+        url = "http://%s:%d/metrics" % svc.address
+        with urllib.request.urlopen(url, timeout=5) as r:
+            text = r.read().decode()
+    finally:
+        svc.close()
+        s.result()
+    exported = {line.split(" ")[0] for line in text.splitlines()
+                if line.startswith("gapp_span_")}
+    assert exported == want | {"gapp_span_records_dropped"}
