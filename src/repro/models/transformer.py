@@ -3,7 +3,10 @@
 Layers run as a python loop over ``num_groups`` pattern groups (straight-line
 HLO: best overlap and honest ``cost_analysis``) or as ``lax.scan`` over
 stacked group params (compact HLO for very deep configs) — ``scan_layers``
-selects.  Activation remat wraps each group when ``cfg.remat``.
+selects.  When ``cfg.remat``, each group runs under ``jax.checkpoint``; the
+training step picks its save policy (``repro.train.step.pick_remat_policy``):
+the weight matmuls' outputs are kept where they fit beside the step's
+arguments in the device's memory, and everything is recomputed otherwise.
 """
 from __future__ import annotations
 
@@ -11,6 +14,7 @@ import functools
 from typing import Any
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 
 from repro.models import blocks as blk
@@ -100,14 +104,10 @@ def encode(params, frontend_feats, cfg: ModelConfig):
     return rms_norm(x, params["enc_norm"])
 
 
-def forward(params, batch: dict, cfg: ModelConfig, *, scan_layers=False,
-            local_impl="mask"):
-    """Full-sequence forward -> (logits, aux).
-
-    batch keys: "tokens" (B,S) int32; optional "frontend" (B,Sf,frontend_dim)
-    (audio frames / vision patches, precomputed per the assignment stub);
-    optional "positions".
-    """
+def _stack_inputs(params, batch: dict, cfg: ModelConfig):
+    """What every layer group takes besides its params: the residual stream
+    (embedded tokens after any patch prefix), its positions and, for
+    encoder-decoder models, the encoder memory and its positions."""
     tokens = batch["tokens"]
     b, s = tokens.shape
     x = _embed(params, tokens, cfg)
@@ -124,12 +124,25 @@ def forward(params, batch: dict, cfg: ModelConfig, *, scan_layers=False,
     positions = batch.get("positions")
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    return x, positions, memory, memory_positions
 
+
+def forward(params, batch: dict, cfg: ModelConfig, *, scan_layers=False,
+            local_impl="mask", remat_policy=None):
+    """Full-sequence forward -> (logits, aux).
+
+    batch keys: "tokens" (B,S) int32; optional "frontend" (B,Sf,frontend_dim)
+    (audio frames / vision patches, precomputed per the assignment stub);
+    optional "positions".  ``remat_policy`` is the save policy of the
+    per-group checkpoint when ``cfg.remat`` (None: save nothing).
+    """
+    x, positions, memory, memory_positions = _stack_inputs(params, batch,
+                                                           cfg)
     gfn = functools.partial(_group_fn, cfg=cfg, memory=memory,
                             memory_positions=memory_positions,
                             local_impl=local_impl)
     if cfg.remat:
-        gfn = jax.checkpoint(gfn, static_argnums=())
+        gfn = jax.checkpoint(gfn, policy=remat_policy)
     aux_total = None
     if scan_layers:
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *params["groups"])
@@ -152,13 +165,53 @@ def forward(params, batch: dict, cfg: ModelConfig, *, scan_layers=False,
                                 local_impl=local_impl,
                                 pattern=cfg.tail_pattern)
         if cfg.remat:
-            tfn = jax.checkpoint(tfn)
+            tfn = jax.checkpoint(tfn, policy=remat_policy)
         x, aux = tfn(params["tail"], x, positions)
         if aux:
             aux_total = aux if aux_total is None else jax.tree.map(
                 jnp.add, aux_total, aux)
     logits = _unembed(params, x, cfg)
     return logits, (aux_total or {})
+
+
+def saved_residuals(f, *args) -> list:
+    """Avals of what ``jax.linearize`` keeps of ``f`` for its backward,
+    leaving out the arguments themselves.  Traced on abstract values:
+    nothing is computed or compiled."""
+    closed, (_, f_jvp) = jax.make_jaxpr(
+        lambda *a: jax.linearize(f, *a), return_shape=True)(*args)
+    jaxpr = closed.jaxpr
+    n = len(jax.tree.leaves(f_jvp))
+    given = set(jaxpr.invars) | set(jaxpr.constvars)
+    kept = {v for v in jaxpr.outvars[len(jaxpr.outvars) - n:]
+            if isinstance(v, jax.extend.core.Var) and v not in given}
+    return [v.aval for v in kept]
+
+
+def remat_saved_bytes(params, batch: dict, cfg: ModelConfig, policy, *,
+                      local_impl="mask") -> int:
+    """Bytes the per-group checkpoint keeps for the backward under
+    ``policy``, over every group and the tail, counted on abstract values
+    of ``params`` and ``batch`` (one group is traced per pattern)."""
+    params, batch = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (params, batch))
+    x, positions, memory, memory_positions = jax.eval_shape(
+        functools.partial(_stack_inputs, cfg=cfg), params, batch)
+
+    def group_bytes(gparams, pattern):
+        def fn(gp, x, positions, memory, memory_positions):
+            return _group_fn(gp, x, positions, cfg, memory=memory,
+                             memory_positions=memory_positions,
+                             local_impl=local_impl, pattern=pattern)
+        kept = saved_residuals(jax.checkpoint(fn, policy=policy), gparams, x,
+                               positions, memory, memory_positions)
+        return sum(a.size * a.dtype.itemsize for a in kept)
+
+    total = cfg.num_groups * group_bytes(params["groups"][0],
+                                         cfg.block_pattern)
+    if cfg.tail_pattern:
+        total += group_bytes(params["tail"], cfg.tail_pattern)
+    return total
 
 
 def lm_loss(params, batch: dict, cfg: ModelConfig, **fw_kwargs):

@@ -21,7 +21,7 @@ from repro.models import init_lm
 from repro.models.common import ModelConfig
 from repro.obs import spans
 from repro.optim import adamw
-from repro.train.step import make_train_step
+from repro.train.step import make_train_step, remat_stats
 
 
 @dataclasses.dataclass
@@ -67,6 +67,17 @@ class Trainer:
             if self.gapp else None
         self.history: list[dict] = []
         self._ckpt_thread = None
+        self._remat_seen = remat_stats()
+
+    def _log_remat(self) -> None:
+        """One line per new trace of the step: whether its backward keeps
+        the weight matmuls' outputs or recomputes them."""
+        st = remat_stats()
+        if st == self._remat_seen:
+            return
+        self._remat_seen = st
+        print(f"remat: {st['last']} (saved {st['saved_bytes']} B per "
+              f"device, budget {st['budget_bytes']} B)", flush=True)
 
     def init_state(self, key=None):
         key = key if key is not None else jax.random.PRNGKey(self.tcfg.seed)
@@ -124,6 +135,7 @@ class Trainer:
                 if g:
                     g.end(self.w_train)
                 with spans.span("train/host"):
+                    self._log_remat()
                     self.history.append(
                         {k: float(np.asarray(v)) for k, v in metrics.items()
                          if v is not None and np.ndim(v) == 0})

@@ -26,7 +26,7 @@ from repro.launch.dryrun import rules_for
 from repro.models import init_lm, forward
 from repro.optim import adamw
 from repro.sharding import api as shapi, params as shparams
-from repro.train.step import make_train_step
+from repro.train.step import make_train_step, remat_stats
 
 from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
@@ -57,7 +57,8 @@ for arch in json.loads(os.environ["ARCHS"]):
         loss1 = float(m["loss"])
         p3, o3, m2, _ = step(p2, o2, batch, None)
         out[arch] = {"loss0": loss1, "loss1": float(m2["loss"]),
-                     "finite": bool(jnp.isfinite(m2["loss"]))}
+                     "finite": bool(jnp.isfinite(m2["loss"])),
+                     "remat": remat_stats()["last"]}
 print("RESULT " + json.dumps(out))
 """
 
@@ -80,6 +81,8 @@ def test_sharded_train_step_8dev(archs):
         assert res["finite"], (arch, res)
         # two steps on the same batch: loss must drop
         assert res["loss1"] < res["loss0"], (arch, res)
+        # host devices report no byte limit: the step keeps full remat
+        assert res["remat"] == "full_remat", (arch, res)
 
 
 GPIPE_SCRIPT = r"""
