@@ -8,16 +8,93 @@ Covers every assigned attention variant:
   * bidirectional encoder attention and cross attention (seamless enc-dec)
   * decode against a KV cache, including the sequence-sharded two-pass
     flash-decode combine used when the cache is sharded over the model axis.
+
+Full-sequence causal self-attention takes the fused splash kernel
+(``repro.kernels.attention``) where the trace can observe that it applies:
+a TPU, one device, positions 0..S-1, no window and no soft cap, and shapes
+the kernel tiles.  Every other call takes the einsum path (``_sdpa``), the
+reference.  ``attention_stats()`` counts the choices.
 """
 from __future__ import annotations
+
+import contextlib
+import threading
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import attention as attn_kernel
 from repro.models.common import ModelConfig, dense_init, rms_norm, rope, softcap
-from repro.sharding.api import constrain
+from repro.sharding.api import bound_device, constrain, current_binding
 
 NEG_INF = -2.3819763e38
+
+_stats_lock = threading.Lock()
+_stats = dict(splash=0, einsum=0, reason=None,  # guarded-by: _stats_lock
+              block_q=None, block_kv=None)
+_layers = threading.local()
+
+
+def attention_stats() -> dict:
+    """Full-sequence attention layers of the traced programs that took the
+    splash kernel (``splash``) or the einsum path (``einsum``), the last
+    fallback's reason (``platform``, ``mask``, ``softcap``, ``mesh`` or
+    ``shape``) and the last kernel's block sizes.  A traced call counts
+    the layers it stands for (``counted_as``)."""
+    with _stats_lock:
+        return dict(_stats)
+
+
+@contextlib.contextmanager
+def counted_as(layers: int):
+    """Each attention call traced inside stands for ``layers`` layers: a
+    layer group traced once for all its repeats (a checkpoint's cached
+    trace, a scan's body) counts each repeat, and 0 leaves
+    ``attention_stats()`` as it is (abstract evaluations that are not the
+    step's layers, and repeats whose trace was counted already)."""
+    prev = getattr(_layers, "n", 1)
+    _layers.n = prev * layers
+    try:
+        yield
+    finally:
+        _layers.n = prev
+
+
+def _count(reason: str | None, blocks=None) -> None:
+    n = getattr(_layers, "n", 1)
+    if not n:
+        return
+    with _stats_lock:
+        if reason is None:
+            _stats["splash"] += n
+            _stats.update(block_q=blocks.block_q, block_kv=blocks.block_kv)
+        else:
+            _stats["einsum"] += n
+            _stats["reason"] = reason
+
+
+def _platform() -> str:
+    """Platform of the device the traced step runs on."""
+    return bound_device().platform
+
+
+def _kernel_fallback(x, cfg: ModelConfig, *, window, causal, kv_x,
+                     arange_positions: bool) -> str | None:
+    """Why this call keeps the einsum path, or None where the kernel
+    applies."""
+    if _platform() != "tpu":
+        return "platform"
+    if kv_x is not None or not causal or window is not None \
+            or not arange_positions:
+        return "mask"
+    if cfg.logits_softcap:
+        return "softcap"
+    binding = current_binding()
+    if binding is not None and binding[0].devices.size > 1:
+        return "mesh"
+    if not attn_kernel.supports(x.shape[1], cfg.hd):
+        return "shape"
+    return None
 
 
 def init_attention(key, cfg: ModelConfig, cross: bool = False) -> dict:
@@ -124,23 +201,44 @@ def _band_mask(q_pos, k_pos, window: int | None, causal: bool):
 
 
 def attention(p, x, positions, cfg: ModelConfig, *, window: int | None,
-              causal: bool = True, kv_x=None, kv_positions=None) -> jnp.ndarray:
+              causal: bool = True, kv_x=None, kv_positions=None,
+              arange_positions: bool = False) -> jnp.ndarray:
     """Full-sequence attention (training / prefill).  ``kv_x`` switches to
-    cross attention (keys/values from encoder memory, no causal mask)."""
+    cross attention (keys/values from encoder memory, no causal mask).
+    ``arange_positions`` says that ``positions`` is 0..S-1 in every row, so
+    that the causal mask is exactly the kernel's."""
+    reason = _kernel_fallback(x, cfg, window=window, causal=causal,
+                              kv_x=kv_x, arange_positions=arange_positions)
     q = _project_q(p, x, cfg, positions)
     if kv_x is None:
         k, v = _project_kv(p, x, cfg, positions)
-        mask = _band_mask(positions, positions, window, causal)
     else:
         k, v = _project_kv(p, kv_x, cfg, kv_positions)
+    if reason is None:
+        out = _splash(q, k, v, cfg)
+    else:
+        _count(reason)
         mask = None
-    if mask is not None:
-        mask = mask[:, None, None]            # (B, 1, 1, Sq, Sk)
-    out = _sdpa(q, k, v, mask, cfg)
+        if kv_x is None:
+            mask = _band_mask(positions, positions, window,
+                              causal)[:, None, None]   # (B, 1, 1, Sq, Sk)
+        out = _sdpa(q, k, v, mask, cfg)
     out = constrain(out, "batch", "seq", "heads", "head_dim")
     b, s, _, _ = out.shape
     y = out.reshape(b, s, -1) @ p["wo"].astype(cfg.compute_dtype)
     return constrain(y, "batch", "seq", "embed")
+
+
+def _splash(q, k, v, cfg: ModelConfig):
+    """``_sdpa`` under the causal mask, through the splash kernel: q, k, v
+    transposed to heads-major for it and its output back (XLA folds the
+    transposes into the layouts of the projections and the output
+    matmul)."""
+    _count(None, attn_kernel.block_sizes(q.shape[1]))
+    heads = lambda a: a.transpose(0, 2, 1, 3)          # (B, heads, S, hd)
+    out = attn_kernel.causal_attention(heads(q) * (cfg.hd ** -0.5),
+                                       heads(k), heads(v))
+    return heads(out)
 
 
 def attention_blockwise(p, x, positions, cfg: ModelConfig, *,

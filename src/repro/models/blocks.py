@@ -69,8 +69,10 @@ def init_block(key, cfg: ModelConfig, kind: str) -> dict:
 
 
 def apply_block(p, x, positions, cfg: ModelConfig, kind: str, *,
-                memory=None, memory_positions=None, local_impl: str = "mask"):
-    """Full-sequence forward.  Returns (y, aux)."""
+                memory=None, memory_positions=None, local_impl: str = "mask",
+                arange_positions: bool = False):
+    """Full-sequence forward.  Returns (y, aux).  ``arange_positions``:
+    ``positions`` is 0..S-1 in every row (see ``attn_lib.attention``)."""
     aux = {}
     # residual stream lives seq-sharded under SP; intra-block tensors are
     # all-gathered/TP'd and the exit constraint reduce-scatters back
@@ -84,7 +86,8 @@ def apply_block(p, x, positions, cfg: ModelConfig, kind: str, *,
             a = attn_lib.attention_blockwise(p["attn"], h, positions, cfg,
                                              q_chunk=cfg.attn_qchunk)
         else:
-            a = attn_lib.attention(p["attn"], h, positions, cfg, window=None)
+            a = attn_lib.attention(p["attn"], h, positions, cfg, window=None,
+                                   arange_positions=arange_positions)
     elif kind == "local":
         if local_impl == "chunked" and x.shape[1] % cfg.window == 0 \
                 and x.shape[1] >= 2 * cfg.window:
@@ -97,7 +100,8 @@ def apply_block(p, x, positions, cfg: ModelConfig, kind: str, *,
         a = attn_lib.attention(p["attn"], h, positions, cfg, window=None,
                                causal=False)
     elif kind == "cross":
-        a = attn_lib.attention(p["attn"], h, positions, cfg, window=None)
+        a = attn_lib.attention(p["attn"], h, positions, cfg, window=None,
+                               arange_positions=arange_positions)
     elif kind == "rglru":
         a, _ = rec_lib.rglru_block(p["mix"], h, cfg)
     elif kind == "rwkv":

@@ -17,6 +17,7 @@ import jax
 import jax.extend.core
 import jax.numpy as jnp
 
+from repro.models import attention as attn_lib
 from repro.models import blocks as blk
 from repro.models.common import ModelConfig, dense_init, rms_norm, softcap
 from repro.sharding.api import constrain
@@ -80,12 +81,14 @@ def _unembed(params, x, cfg: ModelConfig):
 
 
 def _group_fn(gparams, x, positions, cfg: ModelConfig, *, memory=None,
-              memory_positions=None, local_impl="mask", pattern=None):
+              memory_positions=None, local_impl="mask", pattern=None,
+              arange_positions=False):
     aux_sum = None
     for i, kind in enumerate(pattern or cfg.block_pattern):
         x, aux = blk.apply_block(
             gparams[f"b{i}"], x, positions, cfg, kind, memory=memory,
-            memory_positions=memory_positions, local_impl=local_impl)
+            memory_positions=memory_positions, local_impl=local_impl,
+            arange_positions=arange_positions)
         if aux:
             aux_sum = aux if aux_sum is None else jax.tree.map(
                 jnp.add, aux_sum, aux)
@@ -102,6 +105,12 @@ def encode(params, frontend_feats, cfg: ModelConfig):
     for p in params["encoder"]:
         x, _ = blk.apply_block(p, x, positions, cfg, "encoder")
     return rms_norm(x, params["enc_norm"])
+
+
+def _arange_positions(batch: dict) -> bool:
+    """Whether ``_stack_inputs`` makes the positions, 0..S-1 in every row:
+    the batch gave none.  Known while tracing."""
+    return batch.get("positions") is None
 
 
 def _stack_inputs(params, batch: dict, cfg: ModelConfig):
@@ -121,9 +130,10 @@ def _stack_inputs(params, batch: dict, cfg: ModelConfig):
                   @ params["frontend"].astype(cfg.compute_dtype))
         x = jnp.concatenate([prefix, x], axis=1)
         s = x.shape[1]
-    positions = batch.get("positions")
-    if positions is None:
+    if _arange_positions(batch):
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    else:
+        positions = batch["positions"]
     return x, positions, memory, memory_positions
 
 
@@ -138,24 +148,30 @@ def forward(params, batch: dict, cfg: ModelConfig, *, scan_layers=False,
     """
     x, positions, memory, memory_positions = _stack_inputs(params, batch,
                                                            cfg)
+    arange = _arange_positions(batch)
     gfn = functools.partial(_group_fn, cfg=cfg, memory=memory,
                             memory_positions=memory_positions,
-                            local_impl=local_impl)
+                            local_impl=local_impl, arange_positions=arange)
     if cfg.remat:
         gfn = jax.checkpoint(gfn, policy=remat_policy)
     aux_total = None
+    groups = len(params["groups"])
     if scan_layers:
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *params["groups"])
 
         def body(carry, gparams):
             y, aux = gfn(gparams, carry, positions)
             return y, aux
-        x, auxs = jax.lax.scan(body, x, stacked)
+        with attn_lib.counted_as(groups):
+            x, auxs = jax.lax.scan(body, x, stacked)
         aux_total = None if auxs is None else jax.tree.map(
             lambda a: jnp.sum(a, axis=0), auxs)
     else:
-        for gparams in params["groups"]:
-            x, aux = gfn(gparams, x, positions)
+        for i, gparams in enumerate(params["groups"]):
+            # the first group's trace counts every group's layers, whether
+            # or not the later calls trace again
+            with attn_lib.counted_as(groups if i == 0 else 0):
+                x, aux = gfn(gparams, x, positions)
             if aux:
                 aux_total = aux if aux_total is None else jax.tree.map(
                     jnp.add, aux_total, aux)
@@ -163,7 +179,8 @@ def forward(params, batch: dict, cfg: ModelConfig, *, scan_layers=False,
         tfn = functools.partial(_group_fn, cfg=cfg, memory=memory,
                                 memory_positions=memory_positions,
                                 local_impl=local_impl,
-                                pattern=cfg.tail_pattern)
+                                pattern=cfg.tail_pattern,
+                                arange_positions=arange)
         if cfg.remat:
             tfn = jax.checkpoint(tfn, policy=remat_policy)
         x, aux = tfn(params["tail"], x, positions)
@@ -192,7 +209,9 @@ def remat_saved_bytes(params, batch: dict, cfg: ModelConfig, policy, *,
                       local_impl="mask") -> int:
     """Bytes the per-group checkpoint keeps for the backward under
     ``policy``, over every group and the tail, counted on abstract values
-    of ``params`` and ``batch`` (one group is traced per pattern)."""
+    of ``params`` and ``batch`` (one group is traced per pattern, on the
+    attention path the step takes, and left out of ``attention_stats``)."""
+    arange = _arange_positions(batch)
     params, batch = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (params, batch))
     x, positions, memory, memory_positions = jax.eval_shape(
@@ -202,9 +221,12 @@ def remat_saved_bytes(params, batch: dict, cfg: ModelConfig, policy, *,
         def fn(gp, x, positions, memory, memory_positions):
             return _group_fn(gp, x, positions, cfg, memory=memory,
                              memory_positions=memory_positions,
-                             local_impl=local_impl, pattern=pattern)
-        kept = saved_residuals(jax.checkpoint(fn, policy=policy), gparams, x,
-                               positions, memory, memory_positions)
+                             local_impl=local_impl, pattern=pattern,
+                             arange_positions=arange)
+        with attn_lib.counted_as(0):
+            kept = saved_residuals(jax.checkpoint(fn, policy=policy),
+                                   gparams, x, positions, memory,
+                                   memory_positions)
         return sum(a.size * a.dtype.itemsize for a in kept)
 
     total = cfg.num_groups * group_bytes(params["groups"][0],

@@ -88,6 +88,13 @@ def current_binding():
     return getattr(_state, "binding", None)
 
 
+def bound_device():
+    """A device the traced computation runs on: the bound mesh's first, or
+    the default device when nothing is bound."""
+    binding = current_binding()
+    return binding[0].devices.flat[0] if binding else jax.devices()[0]
+
+
 def axis_size(name: str) -> int:
     """Size of the physical axis a logical name maps to (1 if unbound)."""
     b = current_binding()

@@ -8,8 +8,9 @@ ready for ``jax.jit`` with the shardings produced by
 
 With ``cfg.remat`` the step picks the per-group checkpoint's save policy
 while it is traced (``pick_remat_policy``): keep the outputs of the weight
-matmuls when they fit the device's memory beside the step's arguments, so
-the backward does not run them again, and recompute everything otherwise.
+matmuls (and of the attention kernel) when they fit the device's memory
+beside the step's arguments, so the backward does not run them again, and
+recompute everything otherwise.
 ``remat_stats()`` counts the choices.
 """
 from __future__ import annotations
@@ -20,15 +21,20 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import attention as attn_kernel
 from repro.models import lm_loss
 from repro.models.common import ModelConfig
 from repro.models.transformer import remat_saved_bytes
 from repro.optim import adamw, compression
-from repro.sharding.api import axis_size, constrain, current_binding
+from repro.sharding.api import axis_size, bound_device, constrain
 
-# Keeps the outputs of matmuls with no batch dimensions: the weight
-# projections, not the attention scores.
-SAVE_DOTS = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+# Keeps the outputs of matmuls with no batch dimensions (the weight
+# projections, not the attention scores) and, where attention runs the
+# splash kernel, its output and log-sum-exp: the backward then runs no
+# forward kernel again.
+SAVE_DOTS = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(attn_kernel.RESIDUALS))
 # Share of the device's byte limit left to the step's own working set
 # (gradients, logits, what full remat keeps); see PERF.md, model step.
 WORKING_SHARE = 0.25
@@ -50,10 +56,8 @@ def remat_stats() -> dict:
 def device_bytes_limit() -> int | None:
     """Memory one device of the step has, or None where the backend reports
     no limit (CPU) or the devices are described, not attached."""
-    binding = current_binding()
-    device = binding[0].devices.flat[0] if binding else jax.devices()[0]
     try:
-        stats = device.memory_stats()
+        stats = bound_device().memory_stats()
     except jax.errors.JaxRuntimeError:
         return None
     return (stats or {}).get("bytes_limit")

@@ -18,6 +18,7 @@ from repro.ckpt import checkpoint
 from repro.core.session import ProfileSession
 from repro.data.pipeline import PrefetchLoader, SyntheticLM
 from repro.models import init_lm
+from repro.models.attention import attention_stats
 from repro.models.common import ModelConfig
 from repro.obs import spans
 from repro.optim import adamw
@@ -68,16 +69,30 @@ class Trainer:
         self.history: list[dict] = []
         self._ckpt_thread = None
         self._remat_seen = remat_stats()
+        self._attn_seen = attention_stats()
 
-    def _log_remat(self) -> None:
-        """One line per new trace of the step: whether its backward keeps
-        the weight matmuls' outputs or recomputes them."""
+    def _log_trace_choices(self) -> None:
+        """Lines for each new trace of the step: whether its backward keeps
+        the weight matmuls' outputs or recomputes them, and how many of its
+        attention layers take the splash kernel."""
         st = remat_stats()
-        if st == self._remat_seen:
-            return
-        self._remat_seen = st
-        print(f"remat: {st['last']} (saved {st['saved_bytes']} B per "
-              f"device, budget {st['budget_bytes']} B)", flush=True)
+        if st != self._remat_seen:
+            self._remat_seen = st
+            print(f"remat: {st['last']} (saved {st['saved_bytes']} B per "
+                  f"device, budget {st['budget_bytes']} B)", flush=True)
+        at, seen = attention_stats(), self._attn_seen
+        if at != seen:
+            self._attn_seen = at
+            splash = at["splash"] - seen["splash"]
+            einsum = at["einsum"] - seen["einsum"]
+            how = []
+            if splash:
+                how.append(f"block_q {at['block_q']}, "
+                           f"block_kv {at['block_kv']}")
+            if einsum:
+                how.append(f"einsum: {at['reason']}")
+            print(f"attention: splash {splash}/{splash + einsum} layers "
+                  f"({'; '.join(how)})", flush=True)
 
     def init_state(self, key=None):
         key = key if key is not None else jax.random.PRNGKey(self.tcfg.seed)
@@ -135,7 +150,7 @@ class Trainer:
                 if g:
                     g.end(self.w_train)
                 with spans.span("train/host"):
-                    self._log_remat()
+                    self._log_trace_choices()
                     self.history.append(
                         {k: float(np.asarray(v)) for k, v in metrics.items()
                          if v is not None and np.ndim(v) == 0})

@@ -12,6 +12,7 @@ import pytest
 from jax.ad_checkpoint import print_saved_residuals
 
 from repro import configs
+from repro.models import attention as attn_lib
 from repro.models import init_lm, lm_loss, transformer
 from repro.optim import adamw
 from repro.train import step as step_lib
@@ -224,3 +225,72 @@ def test_trainer_logs_the_choice_once_per_compile(tmp_path, capsys):
              if ln.startswith("remat:")]
     assert lines == ["remat: full_remat (saved None B per device, "
                      "budget None B)"]
+
+
+def _kernel_cfg():
+    """internvl2's tiny config with heads the splash kernel tiles: 8 patch
+    and 120 text positions, 128 wide."""
+    return dataclasses.replace(configs.get_tiny("internvl2-2b"), head_dim=128)
+
+
+def test_kernel_path_saved_bytes_agree_with_saved_residuals(monkeypatch):
+    """With the splash kernel selected (the platform probe reads a TPU; the
+    kernel is interpreted here), the per-group count, the policy's choice
+    and the whole loss's residuals agree."""
+    monkeypatch.setattr(attn_lib, "_platform", lambda: "tpu")
+    cfg = _kernel_cfg()
+    params = _abstract(init_lm(KEY, cfg))
+    batch = _abstract(_batch(cfg, s=120))
+    before = attn_lib.attention_stats()
+
+    def whole(policy):
+        kept = transformer.saved_residuals(
+            lambda p, b: lm_loss(p, b, cfg, remat_policy=policy)[0], params,
+            batch)
+        return sum(a.size * a.dtype.itemsize for a in kept)
+
+    kept = whole(step_lib.SAVE_DOTS) - whole(None)
+    # beside the weight matmuls' outputs, each layer keeps the kernel's
+    # output and log-sum-exp: (B, H, S, hd) in bf16 and (B, H, S) in f32
+    b, s = 2, 120 + cfg.num_prefix
+    per_layer = b * cfg.num_heads * s * (cfg.hd * 2 + 4)
+    dots = whole(jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    assert kept - (dots - whole(None)) == cfg.num_layers * per_layer
+    st = attn_lib.attention_stats()
+    assert st["splash"] > before["splash"] and st["einsum"] == before["einsum"]
+    counted = transformer.remat_saved_bytes(params, batch, cfg,
+                                            step_lib.SAVE_DOTS)
+    assert counted == kept > 0
+    # the count's own traces are not the step's calls
+    assert attn_lib.attention_stats() == st
+    opt = _abstract(adamw.init(params))
+    assert step_lib.pick_remat_policy(
+        cfg, params, opt, batch, bytes_limit=1 << 40) is step_lib.SAVE_DOTS
+    assert step_lib.remat_stats()["saved_bytes"] == counted
+
+
+# the attention kernel's output (B, H, S, hd) bf16 and log-sum-exp (B, H,
+# S) f32 over the cell's 8 layers
+KERNEL_KEPT = 8 * (2 * 16 * 1280 * 128 * 2 + 2 * 16 * 1280 * 4)
+
+
+@pytest.mark.parametrize("platform,path,kept", [
+    ("cpu", "einsum", 922_746_880),
+    ("tpu", "splash", 922_746_880 + KERNEL_KEPT)])
+def test_internvl2_cell_saved_bytes(platform, path, kept, monkeypatch):
+    """The benchmark cell's step (8 layers at published widths, 2 x (256 +
+    1024) positions), counted on abstract values on either attention
+    path: the weight matmuls' outputs, 922,746,880 B, and on the kernel's
+    path its output and log-sum-exp too."""
+    monkeypatch.setattr(attn_lib, "_platform", lambda: platform)
+    cfg = dataclasses.replace(configs.get_config("internvl2-2b"),
+                              num_layers=8)
+    params = jax.eval_shape(lambda: init_lm(KEY, cfg))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 1024), jnp.int32),
+             "frontend": jax.ShapeDtypeStruct((2, 256, cfg.frontend_dim),
+                                              jnp.float32)}
+    before = attn_lib.attention_stats()[path]
+    assert transformer.remat_saved_bytes(
+        params, batch, cfg, step_lib.SAVE_DOTS) == kept
+    jax.eval_shape(lambda p, b: lm_loss(p, b, cfg)[0], params, batch)
+    assert attn_lib.attention_stats()[path] > before

@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 fold_mod = importlib.import_module("repro.kernels.cmetric_fold")
 hist_mod = importlib.import_module("repro.kernels.tag_hist")
 ops = importlib.import_module("repro.kernels.ops")
+attn_mod = importlib.import_module("repro.kernels.attention")
 
 EVENTS = 1 << 22
 PIPELINE_EVENTS = 1 << 20
@@ -90,3 +91,21 @@ def test_fused_pipeline_compiles(one_chip):
         _spec((PIPELINE_EVENTS,), jnp.int32, one_chip),
         _spec((PIPELINE_EVENTS,), jnp.int32, one_chip),
         num_workers=64, block=2048, interpret=False))
+
+
+def test_causal_attention_compiles(one_chip):
+    """The training cell's attention (internvl2: 16 query and 8 KV heads
+    of 128, 2 x 1280 positions): the forward and the fused backward."""
+    b, h, kv, s, hd = 2, 16, 8, 1280, 128
+
+    def loss(q, k, v):
+        out = attn_mod.causal_attention(q, k, v, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _spec((b, h, s, hd), jnp.bfloat16, one_chip),
+        _spec((b, kv, s, hd), jnp.bfloat16, one_chip),
+        _spec((b, kv, s, hd), jnp.bfloat16, one_chip))
+    text = lowered.compile().as_text()
+    for kernel in ("splash_mqa_fwd", "splash_mqa_dkv"):
+        assert kernel in text
