@@ -3,8 +3,8 @@
 Paper claim: the in-kernel probe is cheap enough for ~4% average overhead.
 Our analogue: the probe microbenchmark (sharded lock-free hot path vs the
 retained locked seed body, single-thread and contended — see
-``bench_probe``), the offline backends' events/second (numpy oracle,
-streaming scan, vectorised, Pallas fold), and the carry-resumable chunked
+``bench_probe``), the offline fold paths' events/second (numpy oracle,
+Pallas fold), and the carry-resumable chunked
 fold's throughput — the numbers behind the PPT column.
 """
 from __future__ import annotations
@@ -13,8 +13,8 @@ import time
 
 import numpy as np
 
-from repro.core import (FoldCarry, compute, compute_numpy, compute_streaming,
-                        compute_vectorized, fold_chunk, synthetic_log)
+from repro.core import (FoldCarry, compute, compute_numpy, fold_chunk,
+                        synthetic_log)
 
 
 def _time(fn, reps=3):
@@ -45,8 +45,6 @@ def run():
     e = len(log)
     backends = {
         "numpy": lambda: compute_numpy(log),
-        "stream": lambda: compute_streaming(log),
-        "vector": lambda: compute_vectorized(log),
         "pallas_interp": lambda: compute(log, backend="pallas"),
     }
     for name, fn in backends.items():

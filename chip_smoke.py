@@ -253,7 +253,7 @@ def _check_report(name: str, rep, oracle, serial_path) -> None:
 
 def phase_replay(seed: int, events: int = REPLAY_EVENTS,
                  chunk: int = REPLAY_CHUNK) -> None:
-    from repro.core import backends, cmetric
+    from repro.core import cmetric
     from repro.core.detector import detect_offline
     from repro.core.session import ProfileSession
     require_native()
@@ -297,7 +297,7 @@ def phase_replay(seed: int, events: int = REPLAY_EVENTS,
         parts.append(tbl.cm)
     log("b. per-slice CMetric relative error, chunked pallas vs numpy: "
         + _slice_rel_err(np.concatenate(parts), full_ref.cm))
-    whole_tbl = backends.compute(cap, "pallas").table
+    whole_tbl = cmetric.compute(cap, "pallas").table
     log("b. per-slice CMetric relative error, whole-log pallas vs numpy: "
         + _slice_rel_err(whole_tbl.cm, full_ref.cm))
     log("b. replay: PASS")

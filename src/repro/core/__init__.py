@@ -13,8 +13,7 @@ around a :class:`~repro.core.session.ProfileSession`::
     sanitize   §3.2 tolerance rules against the carried per-worker state
     fold       carry-resumable ``fold_chunk``/``FoldCarry`` (``cmetric.py``)
                — the paper's Table-1 eBPF-map state, advanced batch-wise;
-               backends registered in ``backends.py``
-               (numpy | stream | vector | pallas)
+               two paths: the numpy float64 oracle | the Pallas device path
     store      accumulated log: in-RAM ``EventStore`` or an append-only
                disk ``SpillStore`` (resident memory O(chunk_events))
         │
@@ -30,8 +29,8 @@ around a :class:`~repro.core.session.ProfileSession`::
 
 ``session.result()`` quiesces and returns the final report — bit-equal on
 the ``numpy`` backend to ``detect_offline`` over the frozen log, for any
-drain/snapshot schedule.  ``Gapp``/``profile_log`` (``profiler.py``) are
-deprecated thin wrappers kept for old call sites.
+drain/snapshot schedule.  ``ProfileSession.offline(log, ...)`` is the same
+session over a finished log.
 
 Multi-host: the :mod:`repro.fleet` package streams drained chunks over a
 socket (``RemoteSink`` → ``IngestServer``, attached via
@@ -40,7 +39,7 @@ one session through ``FleetSource`` — same pipeline, reports carry host
 provenance (``report.worker_hosts`` / per-host exporter lanes).
 
 The offline dataflow (``detect_offline``) is the same pipeline driven
-synchronously: EventLog → sanitize → CMetric backend → SliceTable →
+synchronously: EventLog → sanitize → CMetric fold → SliceTable →
 detector → report; ``detect_offline(chunk_events=...)`` streams it through
 the identical chunk fold in bounded memory.
 """
@@ -49,12 +48,8 @@ from repro.core.events import (ACTIVATE, DEACTIVATE, EventLog, EventRing,
                                synthetic_log, tolerance_keep)
 from repro.core.slices import (CriticalBuffer, CriticalSlice, CriticalTable,
                                SliceTable)
-from repro.core.backends import (available_backends, backends_with,
-                                 backends_with_fold_chunk, get_backend,
-                                 register_backend)
 from repro.core.cmetric import (CMetricResult, FoldCarry, compute,
-                                compute_numpy, compute_streaming,
-                                compute_vectorized, fold_chunk)
+                                compute_numpy, fold_chunk)
 from repro.core.tracer import (LockedTracer, StackRegistry, TagRegistry,
                                Tracer, WorkerHandle)
 from repro.core.sampler import SampleBuffer, SamplingProbe, simulate_samples
@@ -66,16 +61,12 @@ from repro.core.exporters import (available_exporters, export, get_exporter,
                                   register_exporter)
 from repro.core.session import (EventSource, LogSource, ProfileSession,
                                 SpillSource, TracerSource)
-from repro.core.profiler import Gapp, profile_log
 
 __all__ = [
     "ACTIVATE", "DEACTIVATE", "EventLog", "EventRing", "EventStore",
     "ShardedEventRing", "sanitize_chunk", "synthetic_log", "tolerance_keep",
     "SliceTable", "CriticalTable", "CriticalBuffer", "CriticalSlice",
-    "available_backends", "backends_with", "backends_with_fold_chunk",
-    "get_backend", "register_backend",
-    "CMetricResult", "FoldCarry", "compute", "compute_numpy",
-    "compute_streaming", "compute_vectorized", "fold_chunk",
+    "CMetricResult", "FoldCarry", "compute", "compute_numpy", "fold_chunk",
     "StackRegistry", "TagRegistry", "Tracer", "LockedTracer", "WorkerHandle",
     "SampleBuffer", "SamplingProbe", "simulate_samples",
     "BottleneckReport", "PathProfile", "build_report", "detect",
@@ -85,7 +76,6 @@ __all__ = [
     "register_exporter",
     "ProfileSession", "EventSource", "TracerSource", "LogSource",
     "SpillSource",
-    "Gapp", "profile_log",
 ]
 from repro.core.wakers import (classify_report, classify_tag,  # noqa: E402
                                critical_wakers, waker_edges)

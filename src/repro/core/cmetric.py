@@ -11,36 +11,36 @@ trick)::
     cm_hash[w]       += global_cm - local_cm[w]             # on switch-out
     local_cm[w]       = global_cm                           # on switch-in
 
-Four implementations, equivalent up to float tolerance, registered in the
-:mod:`repro.core.backends` registry:
+Two fold paths, chosen by name (:data:`BACKENDS`) in :func:`compute` and
+:func:`fold_chunk`:
 
-* ``numpy``  — :func:`compute_numpy`, float64 oracle (reference for all).
-* ``stream`` — :func:`compute_streaming`, paper-faithful event-at-a-time
-  ``lax.scan`` maintaining exactly the eBPF-map state of Table 1.
-* ``vector`` — :func:`compute_vectorized`, beyond-paper data-parallel
-  formulation (cumsum + stable-sort pairing + segment-sum).  O(E log E)
-  work but fully parallel.
-* ``pallas`` — the vector pipeline with the interval fold swapped for the
-  Pallas ``cmetric_fold`` kernel, fold + pairing + segment-sum fused into a
-  single jitted call (no host round-trip between stages).
+* ``numpy``  — the float64 oracle: :func:`compute_numpy` (event-at-a-time,
+  like the kernel probe) and the exact sequential prefix
+  :func:`_prefix_exact`.
+* ``pallas`` — the device path: :func:`compute_pallas` runs the Pallas
+  ``cmetric_fold`` kernel fused with the pairing and segment-sum stages
+  in one jitted program (:func:`_fused_pipeline`), and the chunk fold's
+  prefix runs on the ``carry_cumsum`` kernel.  Native on a TPU,
+  interpreted elsewhere.
 
-All backends emit a :class:`~repro.core.slices.SliceTable`;
-:class:`CMetricResult` is a thin wrapper over it.
+Both emit a :class:`~repro.core.slices.SliceTable`; :class:`CMetricResult`
+is a thin wrapper over it.
 
-Each backend also registers a **carry-resumable chunk fold**:
-``fold_chunk(carry, chunk) -> (carry, SliceTable)`` advances a
-:class:`FoldCarry` — exactly the paper's Table-1 eBPF-map state — over one
-batch of events.  Replaying *any* partition of a log reproduces the
-whole-log result (bit-equal float64 for ``numpy``, float32 tolerance for
-the device backends), which is what lets the live tracer maintain its
-online state by batches and ``detect_offline(chunk_events=...)`` stream
-unbounded logs in bounded memory.
+The **carry-resumable chunk fold** ``fold_chunk(carry, chunk, backend) ->
+(carry, SliceTable)`` advances a :class:`FoldCarry` — exactly the paper's
+Table-1 eBPF-map state — over one batch of events.  Replaying *any*
+partition of a log reproduces the whole-log result (bit-equal float64 for
+``numpy``, float32 tolerance for ``pallas``), which is what lets the live
+tracer maintain its online state by batches and
+``detect_offline(chunk_events=...)`` stream unbounded logs in bounded
+memory.
 
 Degenerate timeslices (``slice_cm == 0``) fall back to
 ``threads_av = max(n_at_exit, 1)`` — the instantaneous active count at
-switch-out, including the exiting worker — in *every* backend (the numpy
-oracle's semantics; the vector/pallas paths used to hardcode 1.0, which
-could flip criticality between backends).
+switch-out, including the exiting worker — on both paths.
+
+The kernels are imported lazily (:mod:`repro.kernels` sits under this
+module), so ``import repro.core`` never loads ``jax.experimental.pallas``.
 """
 from __future__ import annotations
 
@@ -51,11 +51,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import backends as backends_lib
-from repro.core.backends import register_backend
 from repro.core.events import ACTIVATE, DEACTIVATE, EventLog
 from repro.core.slices import CriticalTable, SliceTable
-from repro.obs import spans
 
 
 @dataclasses.dataclass
@@ -188,86 +185,13 @@ def compute_numpy(log: EventLog) -> CMetricResult:
 
 
 # ---------------------------------------------------------------------------
-# paper-faithful streaming scan (jax.lax.scan over events)
+# fused device pipeline (Pallas fold + pairing + segment-sum)
 # ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("num_workers",))
-def _streaming_scan(times_s, workers, deltas, num_workers: int):
-    """One scan step == one execution of the sched_switch probe function."""
-
-    def step(carry, ev):
-        gcm, idle, count, t_prev, local, start, cm = carry
-        t, wi, d = ev
-        dt = t - t_prev
-        gcm = gcm + jnp.where(count > 0, dt / jnp.maximum(count, 1), 0.0)
-        idle = idle + jnp.where(count > 0, 0.0, dt)
-        is_in = d > 0
-        # switch-in: snapshot local_cm; switch-out: emit timeslice record
-        slice_cm = gcm - local[wi]
-        dur = t - start[wi]
-        local = jnp.where(is_in, local.at[wi].set(gcm), local)
-        start = jnp.where(is_in, start.at[wi].set(t), start)
-        cm = jnp.where(is_in, cm, cm.at[wi].add(slice_cm))
-        count = count + jnp.where(is_in, 1, -1)
-        threads_av = jnp.where(slice_cm > 0, dur / jnp.maximum(slice_cm, 1e-30),
-                               jnp.maximum(count + 1, 1).astype(jnp.float32))
-        out = (~is_in, wi, start[wi] * is_in + (t - dur) * (~is_in), t,
-               slice_cm, threads_av, count + 1)
-        return (gcm, idle, count, t, local, start, cm), out
-
-    zero = jnp.zeros((num_workers,), jnp.float32)
-    carry0 = (jnp.float32(0), jnp.float32(0), jnp.int32(0), times_s[0],
-              zero, zero, zero)
-    carry, outs = jax.lax.scan(step, carry0, (times_s, workers, deltas))
-    gcm, idle, _, _, _, _, cm = carry
-    return cm, idle, outs
-
-
-def compute_streaming(log: EventLog) -> CMetricResult:
-    """Paper-faithful streaming CMetric via ``lax.scan`` (float32 on device)."""
-    e = len(log)
-    if e == 0:
-        return _empty_result(log.num_workers)
-    t = jnp.asarray(log.slice_seconds(), jnp.float32)
-    cm, idle, outs = _streaming_scan(t, jnp.asarray(log.workers),
-                                     jnp.asarray(log.deltas, jnp.int32),
-                                     log.num_workers)
-    is_out, wi, s_start, s_end, s_cm, s_av, s_n = jax.tree.map(np.asarray,
-                                                               outs)
-    m = np.asarray(is_out)
-    # slice start from the scan is reconstructed as end - dur for out events
-    tn = np.asarray(t)
-    return _make_result(log, cm, wi[m], s_start[m], s_end[m], s_cm[m],
-                        s_av[m], log.stacks[m], s_n[m], idle, tn[-1] - tn[0])
-
-
-# ---------------------------------------------------------------------------
-# vectorised (beyond-paper) formulation
-# ---------------------------------------------------------------------------
-
-def _fold_interval_terms(times_s, deltas):
-    """Interval lengths, active counts and the global_cm prefix.
-
-    Returns (n, contrib, gcm) where ``n[i]``/``contrib[i]`` describe interval
-    ``[t_i, t_{i+1})`` (length E-1) and ``gcm[e]`` is the value of global_cm
-    when event ``e`` fires (length E).  This is the part the Pallas
-    ``cmetric_fold`` kernel implements on-device.
-    """
-    dt = times_s[1:] - times_s[:-1]
-    n = jnp.cumsum(deltas)[:-1]                      # active during interval i
-    contrib = jnp.where(n > 0, dt / jnp.maximum(n, 1), 0.0)
-    gcm = jnp.concatenate([jnp.zeros((1,), contrib.dtype), jnp.cumsum(contrib)])
-    idle = jnp.sum(jnp.where(n > 0, 0.0, dt))
-    return n, contrib, gcm, idle
-
 
 def _pair_core(times_s, workers, deltas, gcm, idle, num_workers: int):
-    """Pairing + aggregation stage shared by the vectorised and Pallas
-    backends: ``gcm`` is the global_cm prefix (one entry per event).
-
-    Traceable (un-jitted) so the Pallas backend can fuse it with the fold
-    kernel inside one jit; :func:`compute_vectorized` wraps it in its own.
-    """
+    """Pairing + aggregation stage of the device path: ``gcm`` is the
+    global_cm prefix (one entry per event).  Traced inside
+    :func:`_fused_pipeline`, behind the fold kernel."""
     e = times_s.shape[0]
     # Stable grouping by worker: within a group events alternate IN/OUT, so
     # consecutive (even, odd) positions form a timeslice.
@@ -296,9 +220,15 @@ def _pair_core(times_s, workers, deltas, gcm, idle, num_workers: int):
             n_exit, out_global)
 
 
-@functools.partial(jax.jit, static_argnames=("num_workers",))
-def _vector_pipeline(times_s, workers, deltas, num_workers: int):
-    _, _, gcm, idle = _fold_interval_terms(times_s, deltas)
+@functools.partial(jax.jit, static_argnames=("num_workers", "block",
+                                             "interpret"))
+def _fused_pipeline(times_s, workers, deltas, num_workers: int, block: int,
+                    interpret: bool):
+    """Fold (Pallas kernel) + pairing + segment-sum as ONE jitted program —
+    the gcm prefix never leaves the device between stages."""
+    from repro.kernels import ops
+    _, gcm, _, idle, _ = ops.cmetric_fold(times_s, deltas, block=block,
+                                          interpret=interpret)
     return _pair_core(times_s, workers, deltas, gcm, idle, num_workers)
 
 
@@ -316,7 +246,7 @@ def _result_from_pairing(log: EventLog, t, outs) -> CMetricResult:
 
 
 def drive_pairing(log: EventLog, pipeline) -> CMetricResult:
-    """Shared host driver for pairing-based backends: move the log to device
+    """Host driver of the device path: move the log to device
     arrays, run one jitted ``pipeline(t, workers, deltas, num_workers=...)``
     returning :func:`_pair_core` outputs, and materialise the result table."""
     if len(log) == 0:
@@ -328,18 +258,15 @@ def drive_pairing(log: EventLog, pipeline) -> CMetricResult:
     return _result_from_pairing(log, t, outs)
 
 
-def compute_vectorized(log: EventLog) -> CMetricResult:
-    """Data-parallel CMetric (sort + scans + segment-sum).  Same results as
-    :func:`compute_numpy` up to float32 tolerance; the pairing core is shared
-    with the Pallas fold backend (which swaps in its own gcm prefix)."""
-    return drive_pairing(log, _vector_pipeline)
-
-
-def _compute_pallas(log: EventLog) -> CMetricResult:
-    # Lazy import: keeps jax.experimental.pallas out of plain-numpy users
-    # and avoids a module-level import cycle with repro.kernels.
+def compute_pallas(log: EventLog, *, block: int = 2048,
+                   interpret: bool | None = None) -> CMetricResult:
+    """The device path over a whole log: :func:`_fused_pipeline`, natively
+    compiled on a TPU and interpreted elsewhere.  Same results as
+    :func:`compute_numpy` up to float32 tolerance."""
     from repro.kernels import ops
-    return ops.compute_pallas(log)
+    interpret = ops.default_interpret() if interpret is None else interpret
+    return drive_pairing(log, functools.partial(
+        _fused_pipeline, block=block, interpret=interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -353,10 +280,10 @@ class FoldCarry:
     ``fold_chunk(carry, chunk)`` advances this state by one batch of events
     and emits the batch's completed timeslices; replaying any partition of a
     log through it reproduces the whole-log result — bit-equal to
-    :func:`compute_numpy` for the float64 ``numpy`` chunk backend (every
+    :func:`compute_numpy` on the float64 ``numpy`` path (every
     accumulation is kept strictly sequential: ``np.add.accumulate`` seeded
     with the carried scalar, ``np.add.at`` into the carried per-worker
-    hash), within float32 tolerance for the device backends.
+    hash), within float32 tolerance on the ``pallas`` path.
 
     Fields mirror the eBPF maps: ``global_cm`` (running Σ T_i/n_i), ``idle``
     (time with zero active workers), ``thread_count``, per-worker
@@ -447,33 +374,8 @@ def _prefix_exact(carry: FoldCarry, contrib, idle_contrib):
     return g, float(idle)
 
 
-def _prefix_f32_seq(carry: FoldCarry, contrib, idle_contrib):
-    """Sequential float32 prefix (the streaming scan's arithmetic)."""
-    g = np.add.accumulate(np.concatenate(
-        ([carry.global_cm], contrib)).astype(np.float32))[1:]
-    idle = np.add.accumulate(np.concatenate(
-        ([carry.idle], idle_contrib)).astype(np.float32))[-1]
-    return g.astype(np.float64), float(idle)
-
-
-@jax.jit
-def _cumsum_prefix_f32(g0, i0, contrib, idle_contrib):
-    return g0 + jnp.cumsum(contrib), i0 + jnp.sum(idle_contrib)
-
-
-def _prefix_vector(carry: FoldCarry, contrib, idle_contrib):
-    """Data-parallel float32 prefix (jitted cumsum on device)."""
-    g, idle = _cumsum_prefix_f32(jnp.float32(carry.global_cm),
-                                 jnp.float32(carry.idle),
-                                 jnp.asarray(contrib, jnp.float32),
-                                 jnp.asarray(idle_contrib, jnp.float32))
-    with spans.span("profiler/fold_wait"):
-        jax.block_until_ready((g, idle))
-    return np.asarray(g, np.float64), float(idle)
-
-
 def _prefix_pallas(carry: FoldCarry, contrib, idle_contrib):
-    # Lazy import as for _compute_pallas.
+    """float32 prefix on the Pallas ``carry_cumsum`` kernel."""
     from repro.kernels import ops
     return ops.fold_chunk_prefix(carry.global_cm, carry.idle, contrib,
                                  idle_contrib)
@@ -487,7 +389,8 @@ def _fold_chunk(carry: FoldCarry, log: EventLog, prefix) -> tuple[
     :func:`repro.core.events.sanitize_chunk` on dirty streams first) and
     start at or after ``carry.t_last_ns``.  Returns the same carry object,
     updated, plus one :class:`SliceTable` row per DEACTIVATE in the chunk
-    (in event order, like every backend).
+    (in event order).  ``prefix`` computes the global_cm prefix:
+    :func:`_prefix_exact` or :func:`_prefix_pallas`.
     """
     carry.ensure_workers(log.num_workers)
     e = len(log)
@@ -534,7 +437,7 @@ def _fold_chunk(carry: FoldCarry, log: EventLog, prefix) -> tuple[
         slice_cm > 0, dur / np.where(slice_cm > 0, slice_cm, 1.0),
         np.maximum(n_exit, 1).astype(np.float64))
 
-    # restore event (time) order, the order every backend emits slices in
+    # restore event (time) order, the order both paths emit slices in
     ord2 = np.argsort(out_global, kind="stable")
     w_out = w_out[ord2]
     out_eo = out_global[ord2]
@@ -572,30 +475,29 @@ def _fold_chunk(carry: FoldCarry, log: EventLog, prefix) -> tuple[
     return carry, table
 
 
-def fold_chunk(carry: FoldCarry, log: EventLog,
-               backend: str = "numpy") -> tuple[FoldCarry, SliceTable]:
-    """Dispatch one chunk through the named backend's chunk fold."""
-    return backends_lib.fold_chunk(carry, log, backend=backend)
+BACKENDS = ("numpy", "pallas")
 
 
-def _make_fold_chunk(prefix):
-    return functools.partial(_fold_chunk, prefix=prefix)
-
-
-register_backend("numpy", compute_numpy,
-                 capabilities={"oracle", "float64", "exact"},
-                 fold_chunk=_make_fold_chunk(_prefix_exact))
-register_backend("stream", compute_streaming,
-                 capabilities={"device", "sequential", "paper-faithful"},
-                 fold_chunk=_make_fold_chunk(_prefix_f32_seq))
-register_backend("vector", compute_vectorized,
-                 capabilities={"device", "parallel"},
-                 fold_chunk=_make_fold_chunk(_prefix_vector))
-register_backend("pallas", _compute_pallas,
-                 capabilities={"device", "parallel", "fused", "tpu"},
-                 fold_chunk=_make_fold_chunk(_prefix_pallas))
+def check_backend(name: str) -> str:
+    """``name`` if it names a fold path, else the ``KeyError`` the entry
+    points raise at construction."""
+    if name not in BACKENDS:
+        raise KeyError(f"unknown CMetric backend {name!r}; available: "
+                       f"{', '.join(BACKENDS)}")
+    return name
 
 
 def compute(log: EventLog, backend: str = "numpy") -> CMetricResult:
-    """Dispatch through the :mod:`repro.core.backends` registry."""
-    return backends_lib.compute(log, backend=backend)
+    """CMetric over a whole log on the named path."""
+    if check_backend(backend) == "numpy":
+        return compute_numpy(log)
+    return compute_pallas(log)
+
+
+def fold_chunk(carry: FoldCarry, log: EventLog,
+               backend: str = "numpy") -> tuple[FoldCarry, SliceTable]:
+    """Advance ``carry`` over one chunk on the named path; returns
+    ``(carry, SliceTable)``."""
+    prefix = (_prefix_exact if check_backend(backend) == "numpy"
+              else _prefix_pallas)
+    return _fold_chunk(carry, log, prefix)

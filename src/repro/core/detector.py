@@ -30,7 +30,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.core import backends as backends_lib
+from repro.core import cmetric
 from repro.core.events import EventLog
 from repro.core.sampler import SampleBuffer, simulate_samples
 from repro.core.slices import CriticalSlice, SliceTable
@@ -227,11 +227,11 @@ def _attach_samples(crit: SliceTable, samples: SampleBuffer | None):
 
 
 def pallas_hist_for(backend: str) -> bool:
-    """True when ``backend`` is the fused device pipeline and the Pallas
+    """True when ``backend`` is the ``pallas`` device path and the Pallas
     ``tag_hist`` kernel compiles natively — in interpret mode (off-TPU)
     ``np.bincount`` is far faster, so the histogram is routed to the
     kernel only on real TPU hardware."""
-    if "fused" not in backends_lib.get_backend(backend).capabilities:
+    if backend != "pallas":
         return False
     from repro.kernels import ops
     return not ops.default_interpret()
@@ -434,36 +434,35 @@ def detect_offline(
     worker_names: list[str] | None = None,
     chunk_events: int | None = None,
 ) -> BottleneckReport:
-    """Offline pipeline: recompute CMetric from a raw event log with any
-    registered backend (numpy / stream / vector / pallas), optionally
+    """Offline pipeline: recompute CMetric from a raw event log on either
+    fold path (``numpy`` oracle or ``pallas`` device path), optionally
     replaying the sampling probe, then run the same merge+rank
     post-processing — all stages over the columnar slice table.
 
     Raw logs are sanitized first (spurious double-ACTIVATE / unmatched
     DEACTIVATE are dropped exactly as the live tracer would), so adversarial
-    streams produce the same report on every backend.
+    streams produce the same report on both paths.
 
     ``chunk_events`` streams the fold: the log is pushed through the
-    backend's carry-resumable ``fold_chunk`` in batches of that many
+    carry-resumable ``fold_chunk`` in batches of that many
     events, sanitizing each chunk with carried per-worker state, and only
     the *critical* slice rows are retained between chunks — so arbitrarily
     long logs profile in memory bounded by the chunk size plus the critical
     set.  Results are identical to the whole-log path (bit-equal for the
     float64 ``numpy`` backend).
     """
+    cmetric.check_backend(backend)
     raw_log = log
     if chunk_events is not None and len(log):
-        from repro.core.cmetric import FoldCarry
         from repro.core.events import sanitize_chunk
-        carry = FoldCarry.init(log.num_workers)
+        carry = cmetric.FoldCarry.init(log.num_workers)
         crit_parts = []
         for lo in range(0, len(log), chunk_events):
             part = log.chunk(lo, lo + chunk_events)
             # carry.open is the Table-1 per-worker state: sanitize against
             # it, and the fold advances it after consuming the clean chunk
             part, _, _ = sanitize_chunk(part, carry.open)
-            carry, tbl = backends_lib.fold_chunk(carry, part,
-                                                 backend=backend)
+            carry, tbl = cmetric.fold_chunk(carry, part, backend=backend)
             ct = tbl.critical(n_min)
             if len(ct):
                 crit_parts.append(ct)
@@ -474,7 +473,7 @@ def detect_offline(
             samples = simulate_samples(log.sanitize(), sample_dt_ns, n_min)
     else:
         log = log.sanitize()
-        res = backends_lib.compute(log, backend=backend)
+        res = cmetric.compute(log, backend=backend)
         if samples is None and sample_dt_ns is not None:
             samples = simulate_samples(log, sample_dt_ns, n_min)
         crit = res.critical_table(n_min)

@@ -1,8 +1,8 @@
-"""Report exporter registry — the output-side twin of :mod:`backends`.
+"""Report exporter registry.
 
 Every way of getting a :class:`~repro.core.detector.BottleneckReport` out of
-the profiler registers here under a short name, mirroring the CMetric
-backend registry: ``register_exporter(name, fn, capabilities=...)`` and
+the profiler registers here under a short name:
+``register_exporter(name, fn, capabilities=...)`` and
 ``export(report, fmt, ...)`` dispatches by name, so new output formats plug
 in without touching the pipeline.  Built-ins:
 
@@ -54,9 +54,8 @@ _LAZY_EXPORTERS = {"remote": "repro.fleet.transport"}
 
 def register_exporter(name: str, fn: ExporterFn | None = None, *,
                       capabilities: Iterable[str] = ()) -> ExporterFn:
-    """Register ``fn`` as exporter ``name`` (direct call or decorator, like
-    :func:`repro.core.backends.register_backend`).  Re-registering a name
-    replaces it."""
+    """Register ``fn`` as exporter ``name`` (direct call or decorator).
+    Re-registering a name replaces it."""
     def _register(f: ExporterFn) -> ExporterFn:
         _REGISTRY[name] = Exporter(name, f, frozenset(capabilities))
         return f

@@ -21,8 +21,8 @@ Sources are pluggable (:class:`EventSource`):
 * :class:`TracerSource` — the live sharded tracer (default; created
   implicitly, spans via :meth:`ProfileSession.span` etc.);
 * :class:`LogSource` — offline replay of an :class:`~repro.core.events.EventLog`
-  in ``chunk_events`` batches (what :func:`repro.core.profiler.profile_log`
-  wraps);
+  in ``chunk_events`` batches (what :meth:`ProfileSession.offline`
+  builds);
 * :class:`SpillSource` — replay of a :class:`~repro.core.spill.SpillStore`
   file, one block at a time, so a spilled capture re-analyses in bounded
   memory.
@@ -67,7 +67,7 @@ import time
 from typing import Callable, Iterator
 
 
-from repro.core import backends as backends_lib
+from repro.core import cmetric
 from repro.core import detector as detector_lib
 from repro.core import exporters as exporters_lib
 from repro.core.cmetric import FoldCarry
@@ -225,6 +225,7 @@ class ProfileSession:
                  sample_dt_ns: int | None = None,
                  samples: SampleBuffer | None = None, store=None,
                  max_rows_per_sync: int | None = None):
+        cmetric.check_backend(fold_backend)
         if source is None:
             if store is None and spill_path is not None:
                 store = SpillStore(spill_path, chunk_events=chunk_events)
@@ -274,7 +275,8 @@ class ProfileSession:
                 sample_dt_ns: int | None = None,
                 samples: SampleBuffer | None = None, top_n: int = 10,
                 worker_names: list[str] | None = None) -> "ProfileSession":
-        """Session over a finished log (the `profile_log` shape)."""
+        """Session over a finished log, replayed in ``chunk_events``
+        batches (one chunk when None)."""
         src = LogSource(log, tags, stacks, worker_names, chunk_events)
         return cls(src, n_min=n_min, fold_backend=backend, top_n=top_n,
                    sample_dt_ns=sample_dt_ns, samples=samples,
@@ -419,7 +421,7 @@ class ProfileSession:
             part, _, keep = sanitize_chunk(part, self._carry.open)
             self._sanitize_dropped += int(keep.size - keep.sum())
             with spans.span("profiler/fold"):
-                self._carry, tbl = backends_lib.fold_chunk(
+                self._carry, tbl = cmetric.fold_chunk(
                     self._carry, part, backend=self.fold_backend)
             self._crit.extend_table(tbl, tbl.threads_av < self._resolved_n_min())
             self._folded += len(part)
@@ -575,13 +577,12 @@ class ProfileSession:
             return self.source.full_log()
         raise RuntimeError(f"{type(self.source).__name__} has no full log")
 
-    def offline_report(self, backend: str = "vector",
+    def offline_report(self, backend: str = "numpy",
                        sample_dt_ns: int | None = None,
                        top_n: int | None = None,
                        chunk_events: int | None = None):
-        """Recompute the profile offline from the accumulated log with any
-        registered backend (cross-validates the online numbers; the vector/
-        pallas paths are the fleet-scale post-processing route)."""
+        """Recompute the profile offline from the accumulated log on
+        either fold path (cross-validates the online numbers)."""
         tr = self._live()
         return detector_lib.detect_offline(
             self.freeze(), tr.tags, tr.stacks, tr._resolved_n_min(),

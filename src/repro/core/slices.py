@@ -8,7 +8,7 @@ here replace that with aligned struct-of-arrays:
 
 * :class:`SliceTable` — S aligned columns describing completed timeslices
   (worker, start_ns, end_ns, cm, threads_av, stack_id, n_at_exit).  This is
-  what the CMetric backends emit and what the detector consumes; every
+  what both CMetric fold paths emit and what the detector consumes; every
   pipeline stage over it is a numpy/JAX array op.
 * :class:`CriticalTable` — a :class:`SliceTable` filtered by the criticality
   threshold, remembering the ``n_min`` that produced it.

@@ -56,7 +56,7 @@ from typing import Iterator
 
 import numpy as np
 
-from repro.core import backends as backends_lib
+from repro.core import cmetric
 from repro.core.events import (ACTIVATE, DEACTIVATE, NO_STACK, NO_TAG,
                                EventLog, EventRing, EventStore,
                                ShardedEventRing, tolerance_keep)
@@ -165,11 +165,12 @@ class WorkerHandle:
 class Tracer:
     """Sharded low-overhead span tracer with batched online CMetric.
 
-    ``capacity`` is per worker shard.  ``fold_backend`` selects the
-    registered chunk fold that maintains the online state (``"numpy"`` is
-    the bit-exact float64 default); ``autoflush=False`` disables the
-    opportunistic flush when a shard fills, so a full shard drops new
-    events (counted) like a BPF ring buffer.
+    ``capacity`` is per worker shard.  ``fold_backend`` selects the chunk
+    fold that maintains the online state (``"numpy"``, the bit-exact
+    float64 default, or ``"pallas"``, the device path);
+    ``autoflush=False`` disables the opportunistic flush when a shard
+    fills, so a full shard drops new events (counted) like a BPF ring
+    buffer.
 
     ``store`` is where drained+folded chunks accumulate — anything with the
     :class:`~repro.core.events.EventStore` interface; pass a
@@ -186,7 +187,7 @@ class Tracer:
                  store=None, max_rows_per_sync: int | None = None):
         self.n_min = n_min              # None => total_count/2, resolved lazily
         self.clock = clock
-        self.fold_backend = fold_backend
+        self.fold_backend = cmetric.check_backend(fold_backend)
         self.autoflush = autoflush
         # per-shard decode budget of one flush: caps the Python decode loop
         # a single sync (and therefore a mid-capture snapshot) can run, so a
@@ -279,8 +280,8 @@ class Tracer:
         afford the frame walk the seed paid on every single begin()."""
         if location is None:
             f = sys._getframe(2)
-            # walk out of profiler-internal frames (tracer, session/Gapp
-            # facades, contextlib's @contextmanager machinery) to the user
+            # walk out of profiler-internal frames (tracer, session,
+            # contextlib's @contextmanager machinery) to the user
             # call site
             while f is not None and (
                     (f.f_globals.get("__name__") or "").startswith("repro.core")
@@ -381,7 +382,7 @@ class Tracer:
         stacks_col = np.full(times.shape[0], NO_STACK, np.int32)
         clog = EventLog(times, workers, deltas, tags, stacks_col, w_count)
         with spans.span("profiler/fold"):
-            self._carry, table = backends_lib.fold_chunk(
+            self._carry, table = cmetric.fold_chunk(
                 carry, clog, backend=self.fold_backend)
         # §4.2: intern call paths for critical timeslices only
         crit_mask = table.threads_av < self._resolved_n_min()

@@ -59,7 +59,7 @@ from typing import Callable
 
 import numpy as np
 
-from repro.core import backends as backends_lib
+from repro.core import cmetric
 from repro.core import detector as detector_lib
 from repro.core.events import EventLog
 from repro.core.report import path_entries
@@ -90,6 +90,9 @@ class ReplaySpec:
     worker_hosts: list[str] | None = None
     chunk_events: int | None = None
 
+    def __post_init__(self):
+        cmetric.check_backend(self.backend)
+
     def resolved_worker_names(self, num_workers: int) -> list[str]:
         if self.worker_names:
             return list(self.worker_names)
@@ -118,8 +121,8 @@ def warp_log(log: EventLog, starts_ns: np.ndarray, ends_ns: np.ndarray,
     t = log.times
 
     def snap(x):
-        # slice boundaries are event times by construction, but device
-        # backends round-trip them through float32 — snap to the nearest
+        # slice boundaries are event times by construction, but the device
+        # path round-trips them through float32 — snap to the nearest
         # event so a few-ns perturbation cannot shift the coverage window
         idx = np.searchsorted(t, x)
         lo = np.clip(idx - 1, 0, e - 1)
@@ -338,7 +341,7 @@ def what_if(rep, tag=None, *, shrink: float = 0.0, host: str | None = None,
     kind, value = chosen[0]
 
     clean = spec.log_provider().sanitize()
-    res = backends_lib.compute(clean, backend=spec.backend)
+    res = cmetric.compute(clean, backend=spec.backend)
     crit = res.critical_table(spec.n_min)
     mask, selection = _resolve_target(rep, spec, crit, clean, kind, value)
     matched = int(mask.sum())
@@ -446,7 +449,7 @@ def sensitivity(rep, params: dict | None = None, *,
         knobs.update(params)
 
     clean = spec.log_provider().sanitize()
-    res = backends_lib.compute(clean, backend=spec.backend)
+    res = cmetric.compute(clean, backend=spec.backend)
     wn = spec.resolved_worker_names(clean.num_workers)
 
     def build(n_min: float, samples):

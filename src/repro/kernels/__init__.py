@@ -7,6 +7,6 @@ Each kernel has a pure-jnp oracle in ``ref.py`` and a jit'd wrapper in
 (``interpret=True``) on any other backend.
 """
 from repro.kernels import ops, ref
-from repro.kernels.ops import cmetric_fold, compute_pallas, tag_histogram
+from repro.kernels.ops import cmetric_fold, tag_histogram
 
-__all__ = ["ops", "ref", "cmetric_fold", "compute_pallas", "tag_histogram"]
+__all__ = ["ops", "ref", "cmetric_fold", "tag_histogram"]

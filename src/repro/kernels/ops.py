@@ -3,10 +3,10 @@
 ``interpret`` defaults to False on a TPU backend, where the kernels compile
 natively through Mosaic (TPU v5e is the target), and to True on every
 other backend, where Pallas interprets them (the CPU test suite).
+Wrappers take and return arrays; the CMetric fold paths that drive them
+live in :mod:`repro.core.cmetric`, which imports this module lazily.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -64,25 +64,3 @@ def tag_histogram(tags, weights=None, *, num_bins: int, block: int = 1024,
     return _hist.hist(tags, weights, num_bins=num_bins, block=block,
                       interpret=interpret)
 
-
-@functools.partial(jax.jit, static_argnames=("num_workers", "block",
-                                             "interpret"))
-def _fused_pipeline(times_s, workers, deltas, num_workers: int, block: int,
-                    interpret: bool):
-    """Fold (Pallas kernel) + pairing + segment-sum as ONE jitted program —
-    the gcm prefix never leaves the device between stages."""
-    from repro.core import cmetric as cmetric_lib  # avoid import cycle
-    _, gcm, _, idle, _ = cmetric_fold(times_s, deltas, block=block,
-                                      interpret=interpret)
-    return cmetric_lib._pair_core(times_s, workers, deltas, gcm, idle,
-                                  num_workers)
-
-
-def compute_pallas(log, *, block: int = 2048, interpret: bool | None = None):
-    """CMetric backend: the Pallas fold kernel fused with the shared pairing
-    /aggregation core (see :func:`repro.core.cmetric.drive_pairing`)."""
-    from repro.core import cmetric as cmetric_lib  # avoid import cycle
-    interpret = default_interpret() if interpret is None else interpret
-    return cmetric_lib.drive_pairing(
-        log, functools.partial(_fused_pipeline, block=block,
-                               interpret=interpret))

@@ -46,8 +46,6 @@ class Trainer:
         self.cfg = cfg
         self.opt_cfg = opt_cfg
         self.tcfg = tcfg
-        # ``gapp`` accepts a ProfileSession or the deprecated Gapp facade
-        # (both expose the same span/lifecycle surface).
         self.gapp = gapp if gapp is not None else (
             ProfileSession(dt=0.002) if tcfg.profile else None)
         self.step_fn = step_fn or jax.jit(
@@ -171,6 +169,4 @@ class Trainer:
 
     def profile_report(self, top_n: int = 10):
         assert self.gapp is not None
-        if hasattr(self.gapp, "snapshot"):          # ProfileSession
-            return self.gapp.snapshot(top_n)
-        return self.gapp.report(top_n=top_n)        # deprecated Gapp
+        return self.gapp.snapshot(top_n)

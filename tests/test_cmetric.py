@@ -8,8 +8,8 @@ try:                                   # `python -m pytest` from the repo root
 except ImportError:                    # plain `pytest` (tests/ on sys.path)
     from conftest import given, settings, st
 
-from repro.core import (EventLog, compute_numpy, compute_streaming,
-                        compute_vectorized, compute, synthetic_log)
+from repro.core import EventLog, compute_numpy, compute, synthetic_log
+from repro.core.cmetric import compute_pallas
 from repro.core.events import ACTIVATE, DEACTIVATE, NO_STACK, NO_TAG
 
 
@@ -37,7 +37,7 @@ FIG1 = make_log([
 FIG1_CM = np.array([2 + 1 + 4 / 3 + 1, 1 + 4 / 3, 4 / 3 + 1 + 2])
 
 
-@pytest.mark.parametrize("backend", ["numpy", "stream", "vector", "pallas"])
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
 def test_figure1_hand_example(backend):
     res = compute(FIG1, backend=backend)
     np.testing.assert_allclose(res.per_worker, FIG1_CM, rtol=1e-5)
@@ -85,14 +85,13 @@ def test_backends_agree(num_workers, slices, seed):
     log = synthetic_log(rng, num_workers, slices)
     log.validate()
     r0 = compute_numpy(log)
-    for backend in (compute_streaming, compute_vectorized):
-        r = backend(log)
-        np.testing.assert_allclose(r.per_worker, r0.per_worker,
-                                   rtol=1e-4, atol=1e-6)
-        assert r.num_slices == r0.num_slices
-        np.testing.assert_allclose(np.sort(r.slice_cm),
-                                   np.sort(r0.slice_cm), rtol=1e-3,
-                                   atol=1e-6)
+    r = compute_pallas(log)
+    np.testing.assert_allclose(r.per_worker, r0.per_worker,
+                               rtol=1e-4, atol=1e-6)
+    assert r.num_slices == r0.num_slices
+    np.testing.assert_allclose(np.sort(r.slice_cm),
+                               np.sort(r0.slice_cm), rtol=1e-3,
+                               atol=1e-6)
 
 
 @settings(max_examples=25, deadline=None)
@@ -138,6 +137,6 @@ def test_empty_and_single_event():
         times=np.zeros(0, np.int64), workers=np.zeros(0, np.int32),
         deltas=np.zeros(0, np.int8), tags=np.zeros(0, np.int32),
         stacks=np.zeros(0, np.int32), num_workers=2)
-    for backend in ("numpy", "stream", "vector"):
+    for backend in ("numpy", "pallas"):
         r = compute(empty, backend=backend)
         assert r.num_slices == 0 and r.per_worker.sum() == 0

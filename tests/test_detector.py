@@ -115,7 +115,7 @@ def test_offline_pipeline_with_simulated_sampler():
     tr, clk, w = _bottleneck_trace()
     log = tr.freeze()
     rep = detect_offline(log, tr.tags, tr.stacks, n_min=1.9,
-                         sample_dt_ns=500_000, backend="vector", top_n=5)
+                         sample_dt_ns=500_000, backend="pallas", top_n=5)
     assert rep.total_critical == 8
     top_names = [rep.path_str(p) for p in rep.paths[:2]]
     assert any("io_phase" in n for n in top_names)

@@ -110,9 +110,9 @@ def test_incomplete_checkpoint_rejected(tmp_path):
 
 
 def test_data_pipeline_prefetch_and_profile():
-    from repro.core.profiler import Gapp
+    from repro.core.session import ProfileSession
     from repro.data.pipeline import PrefetchLoader, SyntheticLM
-    g = Gapp(n_min=4)
+    g = ProfileSession(n_min=4)
     src = SyntheticLM(vocab_size=100, seq_len=8, batch_per_host=2)
     loader = PrefetchLoader(src, depth=2, gapp=g)
     batches = [loader.get() for _ in range(5)]

@@ -230,6 +230,10 @@ def test_finish_host_idempotent_and_unknown_false():
         _stream_spans(s, w, clk, 5)
         s.snapshot()
         assert sink.flush(5.0)
+        # flush() returns once the rows are written to the socket; finish
+        # the host only after the server has read them, or the merge can
+        # end before they arrive
+        _wait(lambda: server.stats()["rows_in"] == 10)
         assert server.finish_host("h") is True
         assert server.finish_host("h") is True      # idempotent
         assert server.finish_host("nope") is False

@@ -3,8 +3,8 @@
 The contract (ISSUE 2 tentpole): ``fold_chunk(carry, chunk)`` over *every*
 partition of a log — including size-1 chunks and chunk boundaries that cut
 through open timeslices — must reproduce ``compute_numpy`` on the whole
-log, bit-equal (float64) for the ``numpy`` chunk backend and within float32
-tolerance for the device backends, across all four registered backends.
+log, bit-equal (float64) on the ``numpy`` path and within float32
+tolerance on the ``pallas`` device path.
 """
 import numpy as np
 import pytest
@@ -15,11 +15,11 @@ except ImportError:                    # plain `pytest` (tests/ on sys.path)
     from conftest import given, settings, st
 
 from repro.core import (EventLog, FoldCarry, SliceTable, StackRegistry,
-                        TagRegistry, backends_with_fold_chunk, compute_numpy,
+                        TagRegistry, compute_numpy,
                         detect_offline, fold_chunk, sanitize_chunk,
                         synthetic_log)
 
-ALL_BACKENDS = ("numpy", "stream", "vector", "pallas")
+ALL_BACKENDS = ("numpy", "pallas")
 
 
 def _fold_partition(log, splits, backend):
@@ -64,25 +64,55 @@ def _assert_matches_oracle(log, carry, tbl, exact):
         np.testing.assert_array_equal(tbl.worker, oracle.table.worker)
 
 
-def test_all_backends_register_fold_chunk():
-    assert set(ALL_BACKENDS) <= set(backends_with_fold_chunk())
+PARTITIONS = {
+    "whole": lambda e: [e],                     # single chunk == whole log
+    "size1": lambda e: [1] * e,                 # size-1 chunks
+    "mid_slice": lambda e: [7] * (e // 7 + 1),  # boundary mid-timeslice
+    "ragged": lambda e: [3, 1, e],
+    "one_cut": lambda e: [e // 2, e],
+}
+
+
+@pytest.mark.parametrize("partition", list(PARTITIONS))
+@pytest.mark.parametrize("backend", ALL_BACKENDS)
+def test_deterministic_partitions_match_oracle(backend, partition):
+    rng = np.random.default_rng(3)
+    log = synthetic_log(rng, 5, 30)        # 300 events
+    splits = PARTITIONS[partition](len(log))
+    carry, tbl = _fold_partition(log, splits, backend)
+    _assert_matches_oracle(log, carry, tbl, exact=backend == "numpy")
 
 
 @pytest.mark.parametrize("backend", ALL_BACKENDS)
-def test_deterministic_partitions_match_oracle(backend):
-    rng = np.random.default_rng(3)
-    log = synthetic_log(rng, 5, 30)        # 300 events
-    e = len(log)
-    partitions = [
-        [e],                               # single chunk == whole log
-        [1] * e,                           # size-1 chunks
-        [7] * (e // 7 + 1),                # boundary mid-timeslice
-        [3, 1, e],                         # ragged
-        [e // 2, e],                       # one cut
-    ]
-    for splits in partitions:
-        carry, tbl = _fold_partition(log, splits, backend)
-        _assert_matches_oracle(log, carry, tbl, exact=backend == "numpy")
+def test_workers_registered_mid_stream_match_oracle(backend):
+    """Workers that first appear in a later chunk grow the carry's
+    per-worker maps (``FoldCarry.ensure_workers``), as a live tracer's
+    chunks do while workers register, without disturbing the state of the
+    workers folded before them."""
+    rng = np.random.default_rng(9)
+    early = synthetic_log(rng, 2, 10)      # workers 0, 1
+    late = synthetic_log(rng, 5, 10)       # then 0..4: 2, 3 and 4 join
+    shift = int(early.times[-1]) + 1000
+    cat = lambda a, b: np.concatenate([a, b])
+    log = EventLog(cat(early.times, late.times + shift),
+                   cat(early.workers, late.workers),
+                   cat(early.deltas, late.deltas),
+                   cat(early.tags, late.tags),
+                   cat(early.stacks, late.stacks), 5)
+    log.validate()
+    carry = FoldCarry.init(1)
+    parts = []
+    for lo in range(0, len(log), 7):
+        hi = min(lo + 7, len(log))
+        part = log.chunk(lo, hi)
+        seen = int(log.workers[:hi].max()) + 1      # registered so far
+        part = EventLog(part.times, part.workers, part.deltas, part.tags,
+                        part.stacks, seen)
+        carry, tbl = fold_chunk(carry, part, backend=backend)
+        parts.append(tbl)
+    assert carry.num_workers == 5
+    _assert_matches_oracle(log, carry, SliceTable.concat(parts),
+                           exact=backend == "numpy")
 
 
 @pytest.mark.parametrize("backend", ALL_BACKENDS)

@@ -238,7 +238,7 @@ def test_trainer_spans_once_per_step_and_the_session_log_unchanged(
     assert with_spans["data_loader"][:2] == (0, 0)
 
 
-@pytest.mark.parametrize("backend", ["numpy", "vector", "pallas"])
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
 def test_profiler_spans_at_the_drain_and_the_sampler(log, tmp_path, backend):
     clk = FakeClock()
     tr = Tracer(n_min=2, clock=clk, fold_backend=backend)

@@ -1,10 +1,9 @@
 """Streaming ProfileSession: snapshot-during-capture == offline oracle,
 spill-bounded memory, pluggable sources, exporter registry, live watch,
-and the deprecated Gapp/profile_log wrappers."""
+call-site interning and the offline-session shape."""
 import json
 import threading
 import time
-import warnings
 
 import numpy as np
 import pytest
@@ -293,35 +292,14 @@ def test_watch_via_exporter_and_errors_recorded():
 
 
 # ---------------------------------------------------------------------------
-# deprecated wrappers
+# call-site interning and the offline-session shape
 # ---------------------------------------------------------------------------
 
-def test_gapp_wrapper_delegates_to_session():
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        from repro.core import Gapp
-        g = Gapp(n_min=1.9, clock=FakeClock())
-    assert any(issubclass(w.category, DeprecationWarning) for w in rec)
-    assert isinstance(g.session, ProfileSession)
-    clk = g.tracer.clock
-    a = g.register_worker("a")
-    g.register_worker("b")
-    g.begin(a, "solo")
-    clk.advance(1_000_000)
-    g.end(a)
-    rep = g.report()
-    assert rep.total_critical == 1
-    assert g.session.snapshot().total_critical == 1
-
-
 def test_gapp_begin_callsite_resolved_once_and_loc_override():
-    """Satellite: begin() no longer walks sys._getframe per call — the
-    callsite is interned once per distinct tag and points at the *user*
-    module (not the facade), and loc= overrides it explicitly."""
-    from repro.core import Gapp
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        g = Gapp(n_min=1.0, clock=FakeClock())
+    """begin() does not walk sys._getframe per call — the callsite is
+    interned once per distinct tag and points at the *user* module (not
+    the session), and loc= overrides it explicitly."""
+    g = ProfileSession(n_min=1.0, clock=FakeClock())
     w = g.register_worker("w")
     g.begin(w, "hot_tag")
     g.end(w)
@@ -339,16 +317,13 @@ def test_gapp_begin_callsite_resolved_once_and_loc_override():
     assert g.tracer.tags.locations[tid] == loc
 
 
-def test_profile_log_wrapper_matches_detect_offline():
+def test_offline_session_with_registries_matches_detect_offline():
     rng = np.random.default_rng(5)
     log = synthetic_log(rng, 4, 80)
     oracle = detect_offline(log, TagRegistry(), StackRegistry(), n_min=2.0,
                             sample_dt_ns=1_000_000)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        from repro.core import profile_log
-        rep = profile_log(log, TagRegistry(), StackRegistry(), n_min=2.0,
-                          sample_dt_ns=1_000_000)
+    rep = ProfileSession.offline(log, TagRegistry(), StackRegistry(),
+                                 n_min=2.0, sample_dt_ns=1_000_000).result()
     np.testing.assert_array_equal(rep.per_worker, oracle.per_worker)
     assert _ranked(rep) == _ranked(oracle)
 
@@ -412,15 +387,18 @@ def test_spill_store_owns_its_file_and_readonly_replays(tmp_path):
 # per-shard decode budget (max_rows_per_sync)
 # ---------------------------------------------------------------------------
 
-def test_max_rows_per_sync_bounds_snapshot_decode_and_final_is_exact():
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+def test_max_rows_per_sync_bounds_snapshot_decode_and_final_is_exact(
+        backend):
     """With a decode budget, a mid-capture snapshot folds at most one
     budget's worth per shard (bounded latency, lagging report); close()
-    consumes the backlog so the final report is complete and bit-equal to
-    the offline oracle."""
+    consumes the backlog so the final report is complete and equal to the
+    offline oracle: bit-equal on ``numpy``, within float32 tolerance on
+    ``pallas`` (the attached training cell's drain)."""
     clk = FakeClock()
     budget = 64
     s = ProfileSession(n_min=1.5, clock=clk, capacity=1 << 15,
-                       max_rows_per_sync=budget)
+                       max_rows_per_sync=budget, fold_backend=backend)
     w = [s.register_worker(f"w{i}") for i in range(2)]
     pairs = 2000
     for _ in range(pairs):
@@ -444,7 +422,11 @@ def test_max_rows_per_sync_bounds_snapshot_decode_and_final_is_exact():
     log = s.freeze()
     log.validate()
     res = compute_numpy(log)
-    np.testing.assert_array_equal(res.per_worker, rep.per_worker)
+    if backend == "numpy":
+        np.testing.assert_array_equal(res.per_worker, rep.per_worker)
+    else:
+        np.testing.assert_allclose(rep.per_worker, res.per_worker,
+                                   rtol=1e-4, atol=1e-6)
     assert len(log) == total
 
 
@@ -503,13 +485,13 @@ def test_max_rows_per_sync_full_sync_still_complete():
 
 
 # ---------------------------------------------------------------------------
-# deprecated-wrapper gap: chunk_events reaches the offline session
+# chunk_events reaches the offline session
 # ---------------------------------------------------------------------------
 
-def test_profile_log_forwards_chunk_events(monkeypatch):
-    """profile_log(chunk_events=...) must stream the replay through
-    bounded chunks — pin the forwarding and the result equivalence."""
-    from repro.core import profile_log
+def test_offline_session_forwards_chunk_events(monkeypatch):
+    """ProfileSession.offline(chunk_events=...) must stream the replay
+    through bounded chunks — pin the forwarding and the result
+    equivalence."""
     from repro.core.session import LogSource
     rng = np.random.default_rng(13)
     log = synthetic_log(rng, 4, 120)
@@ -522,10 +504,9 @@ def test_profile_log_forwards_chunk_events(monkeypatch):
             yield part
     monkeypatch.setattr(LogSource, "chunks", spy)
     oracle = detect_offline(log, TagRegistry(), StackRegistry(), n_min=2.0)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        rep = profile_log(log, TagRegistry(), StackRegistry(), n_min=2.0,
-                          sample_dt_ns=None, chunk_events=77)
+    rep = ProfileSession.offline(log, TagRegistry(), StackRegistry(),
+                                 n_min=2.0, sample_dt_ns=None,
+                                 chunk_events=77).result()
     assert seen and max(seen) <= 77 and sum(seen) == len(log)
     np.testing.assert_array_equal(rep.per_worker, oracle.per_worker)
     assert _ranked(rep) == _ranked(oracle)

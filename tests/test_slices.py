@@ -1,6 +1,6 @@
 """Columnar slice-table IR: round-trips, growable buffer, vectorized merge
 vs the retained Python-loop oracle, threads_av fallback consistency, and
-adversarial event streams across all four backends."""
+adversarial event streams on both fold paths."""
 import numpy as np
 import pytest
 
@@ -15,7 +15,7 @@ try:                                   # `python -m pytest` from the repo root
 except ImportError:                    # plain `pytest` (tests/ on sys.path)
     from test_tracer import FakeClock
 
-BACKENDS = ("numpy", "stream", "vector", "pallas")
+BACKENDS = ("numpy", "pallas")
 
 
 def _mklog(events, num_workers):
@@ -234,7 +234,7 @@ def test_threads_av_zero_cm_fallback_consistent():
 
 
 # ---------------------------------------------------------------------------
-# adversarial event streams (paper §3.2 tolerance), all four backends
+# adversarial event streams (paper §3.2 tolerance), both fold paths
 # ---------------------------------------------------------------------------
 
 def _dirty_logs():
@@ -320,9 +320,9 @@ def test_adversarial_streams_agree_across_backends(case):
 
 
 def test_gapp_offline_report_cross_validates_live():
-    from repro.core import Gapp
+    from repro.core import ProfileSession
     clk = FakeClock()
-    g = Gapp(n_min=1.9, clock=clk)
+    g = ProfileSession(n_min=1.9, clock=clk)
     ws = [g.register_worker(f"t{i}") for i in range(3)]
     for _ in range(6):
         for w in ws[:2]:
@@ -333,8 +333,8 @@ def test_gapp_offline_report_cross_validates_live():
         g.begin(ws[2], "serial")
         clk.advance(5_000_000)
         g.end(ws[2])
-    live = g.report()
-    for backend in ("numpy", "vector"):
+    live = g.snapshot()
+    for backend in ("numpy", "pallas"):
         off = g.offline_report(backend=backend)
         np.testing.assert_allclose(off.per_worker, live.per_worker,
                                    rtol=1e-4, atol=1e-9)

@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 # the wrapper function re-exported by ``repro.kernels``
 fold_mod = importlib.import_module("repro.kernels.cmetric_fold")
 hist_mod = importlib.import_module("repro.kernels.tag_hist")
-ops = importlib.import_module("repro.kernels.ops")
+cmetric = importlib.import_module("repro.core.cmetric")
 attn_mod = importlib.import_module("repro.kernels.attention")
 
 EVENTS = 1 << 22
@@ -86,7 +86,7 @@ def test_tag_hist_compiles(one_chip):
 
 
 def test_fused_pipeline_compiles(one_chip):
-    _assert_native_kernel(ops._fused_pipeline.lower(
+    _assert_native_kernel(cmetric._fused_pipeline.lower(
         _spec((PIPELINE_EVENTS,), jnp.float32, one_chip),
         _spec((PIPELINE_EVENTS,), jnp.int32, one_chip),
         _spec((PIPELINE_EVENTS,), jnp.int32, one_chip),

@@ -2,7 +2,8 @@
 import numpy as np
 import pytest
 
-from repro.core import ACTIVATE, DEACTIVATE, Gapp, Tracer, compute_numpy
+from repro.core import (ACTIVATE, DEACTIVATE, ProfileSession, Tracer,
+                        compute_numpy)
 
 
 class FakeClock:
@@ -140,7 +141,7 @@ def test_autoflush_drains_instead_of_dropping():
 
 def test_gapp_facade_live(tmp_path):
     import time
-    g = Gapp(n_min=None, dt=0.001)
+    g = ProfileSession(n_min=None, dt=0.001)
     ws = [g.register_worker(f"t{i}") for i in range(4)]
     with g.running():
         for _ in range(3):
@@ -152,7 +153,7 @@ def test_gapp_facade_live(tmp_path):
             g.begin(ws[3], "bottleneck")
             time.sleep(0.006)
             g.end(ws[3])
-    rep = g.report()
+    rep = g.snapshot()
     assert rep.paths, "no critical paths found"
     assert "bottleneck" in rep.path_str(rep.paths[0])
     assert rep.per_worker.argmax() == 3
